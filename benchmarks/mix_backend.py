@@ -108,6 +108,15 @@ def _worker() -> dict:
 
 
 def run() -> dict:
+    """Time the sweep in an 8-virtual-device CPU worker process.
+
+    A process that holds a TPU cannot hand it to a child, so on a TPU
+    backend this raises at once instead of spawning the worker."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError("benchmarks/mix_backend.py times 8 virtual CPU "
+                           "devices in a child process; it cannot run while "
+                           "this process holds a TPU")
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count="
                          f"{N_DEVICES}",

@@ -310,18 +310,22 @@ ALL = {
 }
 
 
-def main() -> None:
+def main() -> int:
+    """Run the named entries (all by default); non-zero exit if any failed."""
     names = sys.argv[1:] or list(ALL)
     rev = _git_rev()
+    failed = 0
     print("name,us_per_call,derived")
     for name in names:
         try:
             us, derived = ALL[name]()
             print(f"{name},{us:.1f},{derived}", flush=True)
             append_summary(name, us, derived, rev=rev)
-        except Exception as e:  # keep the harness going
+        except Exception as e:  # report every entry, then fail the run
+            failed += 1
             print(f"{name},nan,ERROR:{type(e).__name__}:{e}", flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -46,10 +46,10 @@ def _ring_mix_fp(dims: dict, cfg: dict) -> int:
 
 
 def _quant_mix_fp(dims: dict, cfg: dict) -> int:
-    bc = cfg.get("block_cols", 2048)
-    q = 3 * 32 * bc * _I8          # int8 payloads, (32, block_cols)
-    s = 3 * 32 * 1 * _F32          # per-row scales
-    out = 32 * bc * dims.get("out_itemsize", _F32)
+    bc = cfg.get("block_cols", 32768)
+    q = 3 * bc * _I8               # int8 payloads, one (bc/128, 128) row
+    s = 3 * 8 * 128 * _F32         # (1, 128) scale rows pad to (8, 128)
+    out = bc * dims.get("out_itemsize", _F32)
     return q + s + out
 
 
@@ -60,18 +60,21 @@ def _multi_hop_fp(dims: dict, cfg: dict) -> int:
 
 
 def _multi_hop_quant_fp(dims: dict, cfg: dict) -> int:
-    bf = cfg.get("block_f", 1024)
+    # one launch per hop: the input panel block (int8 at hop 0, the f32
+    # state after), the f32 state out, and per-row (1, 128) scale and
+    # row-max rows, each padded to an (8, 128) tile
+    bf = cfg.get("block_f", 4096)
     rows = dims["rows"]
-    blocks = rows * bf * _I8 + rows * 1 * _F32 + rows * bf * _F32
-    scratch = 2 * rows * 128 * _F32      # |z| max + finalized scales
-    return blocks + _scratch_once(scratch)
+    return 2 * rows * bf * _F32 + 2 * rows * 8 * 128 * _F32
 
 
 def _fused_retract_fp(dims: dict, cfg: dict) -> int:
-    bd, r = cfg.get("block_d", 256), dims["r"]
-    blocks = 3 * bd * r * _F32           # x, g blocks + output block
-    scratch = 4 * r * r * _F32           # B, C, M1, M2 accumulators
-    return blocks + _scratch_once(scratch)
+    from repro.kernels import retract
+    # the kernel's own model (r padded to the lane tile, scratch, live
+    # (r, r) temporaries, double-buffered blocks) is already the whole
+    # figure: halve it so vmem_footprint's uniform x2 leaves it as it is
+    return _scratch_once(retract.vmem_bytes(dims["r"],
+                                            cfg.get("block_d", 256)))
 
 
 def _stiefel_project_fp(dims: dict, cfg: dict) -> int:
@@ -84,7 +87,8 @@ def _stiefel_project_fp(dims: dict, cfg: dict) -> int:
 def _flash_attention_fp(dims: dict, cfg: dict) -> int:
     bq, bk = cfg.get("block_q", 128), cfg.get("block_kv", 128)
     hd, hdv = dims["hd"], dims.get("hdv", dims["hd"])
-    blocks = (bq * _I32 + bk * _I32              # position blocks
+    # positions: a (bq, 1) column and a (1, bk) row, padded to the tile
+    blocks = (bq * 128 * _I32 + 8 * bk * _I32
               + bq * hd * _F32 + bk * hd * _F32 + bk * hdv * _F32
               + bq * hdv * _F32)                 # q, k, v, out
     scratch = (bq * hdv + 2 * bq) * _F32         # acc + m + l
@@ -92,13 +96,14 @@ def _flash_attention_fp(dims: dict, cfg: dict) -> int:
 
 
 def _paged_decode_fp(dims: dict, cfg: dict) -> int:
+    # every block spans all Hkv heads of the fetched pages
     ppb = cfg.get("pages_per_block", 1)
-    ps, group = dims["ps"], dims["group"]
+    ps, group, hkv = dims["ps"], dims["group"], dims["hkv"]
     hd, hdv = dims["hd"], dims.get("hdv", dims["hd"])
-    blocks = (group * hd * _F32
-              + ppb * ps * hd * _F32 + ppb * ps * hdv * _F32
-              + group * hdv * _F32)
-    scratch = (group * hdv + 2 * group) * _F32
+    blocks = hkv * (group * hd * _F32
+                    + ppb * ps * hd * _F32 + ppb * ps * hdv * _F32
+                    + group * hdv * _F32)
+    scratch = hkv * (group * hdv + 2 * group) * _F32
     return blocks + _scratch_once(scratch)
 
 
@@ -125,11 +130,12 @@ REPRESENTATIVE = {
     "ring_mix": {},
     "quant_mix": {"out_itemsize": 4},
     "multi_hop_mix": {"rows": 136, "out_rows": 128},
-    "multi_hop_mix_quant": {"rows": 160},
+    "multi_hop_mix_quant": {"rows": 16},
     "fused_retract": {"r": 128},
     "stiefel_project": {"r": 128},
     "flash_attention": {"hd": 128, "hdv": 128},
-    "paged_decode": {"ps": 64, "group": 8, "hd": 128, "hdv": 128},
+    "paged_decode": {"ps": 64, "group": 8, "hkv": 8, "hd": 128,
+                     "hdv": 128},
 }
 
 
@@ -197,28 +203,37 @@ def check_tiling() -> list[Finding]:
                 f"padded panel ({rows_p},128) not covered by "
                 f"block_rows={block}"))
 
-    # quant_mix: (rows, cols) int8, rows->32 sublanes, cols->128 lanes
+    # quant_mix: each row viewed as (cols/128, 128) int8 lane rows, padded
+    # to whole (32, 128) tiles past one tile height; a block of
+    # block_cols/128 lane rows must be whole tiles or span the row
     for rows in (1, 31, 32, 97):
         for cols in RAGGED_SIZES:
-            rows_p = rows + (-rows) % 32
-            cols_p = cols + (-cols) % 128
-            block_c = _pick(cols_p, [2048, 1024, 512, 256, 128])
-            if rows_p % 32 or cols_p % block_c or cols_p < cols:
+            n = -(-cols // 128)
+            n += (-n) % 32 if n > 32 else 0
+            cols_p = 128 * n
+            block_c = _pick(cols_p, [32768, 16384, 8192, 4096])
+            if cols_p % block_c or cols_p < cols or (
+                    (block_c // 128) % 32 and block_c != cols_p):
                 findings.append(Finding(
                     "tiling", f"quant_mix rows={rows} cols={cols}",
-                    f"padded ({rows_p},{cols_p}) not tiled by "
-                    f"(32,{block_c})"))
+                    f"padded ({rows},{cols_p}) not tiled by "
+                    f"({rows},{block_c // 128},128) blocks"))
 
-    # multi_hop_mix(+quant): lane tail -> 128, row tail -> 8 (fp32) / 32
-    # (int8); block_f fallback chain must always divide the padded width
+    # multi_hop_mix(+quant): the feature tail pads to whole (8, 128) f32 /
+    # (32, 128) int8 tiles; the block_f fallback chain must always divide
     for f in RAGGED_SIZES:
-        f_p = f + (-f) % 128
-        block = _pick(f_p, [1024, 4096, 2048, 512, 256, 128])
-        if f_p % block or f_p < f:
-            findings.append(Finding(
-                "tiling", f"multi_hop_mix f={f}",
-                f"padded width {f_p} not divided by block_f={block} "
-                "(the 128 fallback should always divide a 128-multiple)"))
+        for kernel, tile, cands in (
+                ("multi_hop_mix", 8 * 128,
+                 [1024, 4096, 2048, 1024, 512, 256, 128]),
+                ("multi_hop_mix_quant", 32 * 128,
+                 [4096, 4096, 2048, 1024, 512, 256, 128])):
+            f_p = f + (-f) % tile
+            block = _pick(f_p, cands)
+            if f_p % block or f_p < f or block % tile:
+                findings.append(Finding(
+                    "tiling", f"{kernel} f={f}",
+                    f"padded width {f_p} not divided by whole-tile "
+                    f"block_f={block}"))
 
     # fused_retract / stiefel_project: d,r pad to 128; block_d falls back
     # to 128 whenever the tuned/explicit block does not divide

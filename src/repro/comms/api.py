@@ -99,6 +99,8 @@ class MixBackendProtocol(Protocol):
 
     def est_quant_hop_bytes(self, spec: Any, tree: Any) -> float: ...
 
+    def node_map(self, fn: Any) -> Any: ...
+
 
 # ---------------------------------------------------------------------------
 # backend string registry

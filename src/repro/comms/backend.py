@@ -42,7 +42,6 @@ from typing import Any, Optional, Protocol, Sequence, runtime_checkable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.comms import api
@@ -120,6 +119,11 @@ class MixBackend(Protocol):
         ``quant_ring_hops`` schedule (int8 payload + f32 scale per row)."""
         ...
 
+    def node_map(self, fn):
+        """``fn`` over one node's slices, mapped over node-stacked pytrees
+        (``jax.vmap`` semantics), run where each device's node rows live."""
+        ...
+
 
 # ---------------------------------------------------------------------------
 # stacked (reference) backend
@@ -127,9 +131,23 @@ class MixBackend(Protocol):
 
 
 class StackedBackend:
-    """Node axis = leaf axis 0 everywhere; the repo's original exact paths."""
+    """Node axis = leaf axis 0 everywhere; the repo's original exact paths.
+
+    ``mesh`` (optional) is the device mesh the node-stacked state is
+    sharded over.  Mixes stay whole-array ops that XLA partitions; only
+    node-local work that calls a Pallas kernel runs per device shard.
+    """
 
     name = "stacked"
+
+    def __init__(self, mesh: Optional[Mesh] = None,
+                 axis: str | Sequence[str] = "node"):
+        self.mesh = mesh
+        self.axes: tuple[str, ...] = (axis,) if isinstance(axis, str) \
+            else tuple(axis)
+
+    def node_map(self, fn):
+        return _per_node_shard(self.mesh, self.axes, jax.vmap(fn))
 
     def mix(self, spec, tree: PyTree, steps: int) -> PyTree:
         from repro.core import gossip as G
@@ -166,10 +184,11 @@ class StackedBackend:
         from repro.kernels import ops
         wc = spec.self_weight
         ws = (1.0 - wc) / 2.0
-        return ops.quant_mix(
+        combine = _per_node_shard(self.mesh, self.axes, functools.partial(
+            ops.quant_mix, w_self=wc, w_side=ws, out_dtype=out_dtype))
+        return combine(
             q, jnp.roll(q, 1, 0), jnp.roll(q, -1, 0),
-            scale, jnp.roll(scale, 1, 0), jnp.roll(scale, -1, 0),
-            w_self=wc, w_side=ws, out_dtype=out_dtype)
+            scale, jnp.roll(scale, 1, 0), jnp.roll(scale, -1, 0))
 
     def quant_ring_hops(self, spec, x: Array, steps: int, *,
                         out_dtype=None) -> Array:
@@ -203,7 +222,9 @@ class StackedBackend:
         return float(spec.n_nodes - 1) * total
 
     def __repr__(self):
-        return "StackedBackend()"
+        if self.mesh is None:
+            return "StackedBackend()"
+        return f"StackedBackend(mesh_axes={self.axes})"
 
 
 # ---------------------------------------------------------------------------
@@ -275,9 +296,12 @@ class ShardMapBackend:
         return self.axis_size == 1 or spec.n_nodes < 3
 
     def _shmap(self, fn, tree_specs, out_specs=None):
-        return shard_map(fn, mesh=self.mesh, in_specs=tree_specs,
-                         out_specs=out_specs if out_specs is not None
-                         else self._pspec, check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=tree_specs,
+                             out_specs=out_specs if out_specs is not None
+                             else self._pspec, check_vma=False)
+
+    def node_map(self, fn):
+        return _per_node_shard(self.mesh, self.axes, jax.vmap(fn))
 
     def _perm(self, direction: int):
         d = self.axis_size
@@ -637,6 +661,25 @@ class ShardMapBackend:
 # ---------------------------------------------------------------------------
 
 
+def _per_node_shard(mesh: Optional[Mesh], axes: tuple[str, ...], fn):
+    """``fn`` — node-local work on node-stacked arguments and results — run
+    on each device's own block of node rows.
+
+    XLA cannot partition a Pallas kernel by itself, so node-local work
+    that calls one (the model's flash attention, a combine kernel) is
+    handed its shard through ``shard_map``.  Without a multi-device node
+    axis this is ``fn`` unchanged.
+    """
+    if mesh is None or int(np.prod([mesh.shape[a] for a in axes])) == 1:
+        return fn
+    spec = P(axes if len(axes) > 1 else axes[0])
+
+    def run(*args):
+        return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * len(args),
+                             out_specs=spec, check_vma=False)(*args)
+    return run
+
+
 def dense_power(spec, steps: int) -> Array:
     """``W^steps`` as an f32 constant (float64 numpy power, so it constant-
     folds under jit) — the one dense-matrix artifact both backends share."""
@@ -704,7 +747,7 @@ _DEFAULT_STACKED = StackedBackend()
 
 def _make_stacked(*, mesh=None, axis="node", fuse="auto",
                   fuse_depth=None) -> MixBackend:
-    return _DEFAULT_STACKED
+    return _DEFAULT_STACKED if mesh is None else StackedBackend(mesh, axis)
 
 
 def _make_shard_map(*, mesh=None, axis="node", fuse="auto",

@@ -46,11 +46,13 @@ def _project_back(manifold_map: PyTree, x: PyTree, method: str = "ns") -> PyTree
                         manifold_map, x)
 
 
-def _euclid_grads(problem: MinimaxProblem, x, y, batch):
-    """vmapped (loss, (gx, gy)) — *Euclidean* grads (no tangent projection)."""
+def _euclid_grads(opt, x, y, batch):
+    """Per-node (loss, (gx, gy)) — *Euclidean* grads (no tangent
+    projection) — mapped over the nodes by ``opt``'s mix backend."""
     def one(xi, yi, bi):
-        return jax.value_and_grad(problem.loss_fn, argnums=(0, 1))(xi, yi, bi)
-    return jax.vmap(one)(x, y, batch)
+        return jax.value_and_grad(opt.problem.loss_fn, argnums=(0, 1))(
+            xi, yi, bi)
+    return opt.backend.node_map(one)(x, y, batch)
 
 
 def _metrics(loss, gx, gy, x, y, u) -> StepMetrics:
@@ -103,7 +105,7 @@ class GTGDA:
 
     def init(self, x0: PyTree, y0: Array, batch0: Any) -> GTState:
         x0, y0 = _strong(x0), _strong(y0)
-        _, (gx, gy) = _euclid_grads(self.problem, x0, y0, batch0)
+        _, (gx, gy) = _euclid_grads(self, x0, y0, batch0)
         comm0 = comms_layer.maybe_init_state(
             self.engine, {"x": x0, "y": y0, "u": gx, "v": gy})
         obs0 = self.telemetry.init_counters() if self.telemetry else None
@@ -124,7 +126,7 @@ class GTGDA:
         y_new = jax.vmap(self.problem.project_y)(
             mix("y", state.y, 1) + h.eta * state.v)
 
-        loss, (gx, gy) = _euclid_grads(self.problem, x_new, y_new, batch)
+        loss, (gx, gy) = _euclid_grads(self, x_new, y_new, batch)
         u_new = jax.tree.map(lambda mu, g, gp: mu + g - gp,
                              mix("u", state.u, 1), gx, state.gx_prev)
         v_new = mix("v", state.v, 1) + gy - state.gy_prev
@@ -193,7 +195,7 @@ class DMHSGD:
 
     def init(self, x0: PyTree, y0: Array, batch0: Any) -> HSGDState:
         x0, y0 = _strong(x0), _strong(y0)
-        _, (gx, gy) = _euclid_grads(self.problem, x0, y0, batch0)
+        _, (gx, gy) = _euclid_grads(self, x0, y0, batch0)
         comm0 = comms_layer.maybe_init_state(
             self.engine, {"x": x0, "y": y0, "u": gx, "v": gy})
         obs0 = self.telemetry.init_counters() if self.telemetry else None
@@ -208,8 +210,8 @@ class DMHSGD:
         mix, obs_final = obs_wire.wrap_mixer(
             mix, state.obs, self.gossip, self.engine, self.backend,
             state.comm, state.step)
-        loss, (gx_cur, gy_cur) = _euclid_grads(self.problem, state.x, state.y, batch)
-        _, (gx_old, gy_old) = _euclid_grads(self.problem, state.x_prev, state.y_prev, batch)
+        loss, (gx_cur, gy_cur) = _euclid_grads(self, state.x, state.y, batch)
+        _, (gx_old, gy_old) = _euclid_grads(self, state.x_prev, state.y_prev, batch)
 
         dx = jax.tree.map(lambda g, go, d: g + (1.0 - h.bx) * (d - go),
                           gx_cur, gx_old, state.dx)
@@ -286,7 +288,7 @@ class GTSRVR:
 
     def init(self, x0: PyTree, y0: Array, anchor_batch: Any) -> SRVRState:
         x0, y0 = _strong(x0), _strong(y0)
-        _, (gx, gy) = _euclid_grads(self.problem, x0, y0, anchor_batch)
+        _, (gx, gy) = _euclid_grads(self, x0, y0, anchor_batch)
         cp = _copy_tree
         comm0 = comms_layer.maybe_init_state(
             self.engine, {"x": x0, "y": y0, "u": gx, "v": gy})
@@ -317,15 +319,15 @@ class GTSRVR:
         return x_new, y_new, u_new, v_new, comm_final(), obs_new
 
     def anchor_step(self, state: SRVRState, anchor_batch: Any):
-        loss, (gx, gy) = _euclid_grads(self.problem, state.x, state.y, anchor_batch)
+        loss, (gx, gy) = _euclid_grads(self, state.x, state.y, anchor_batch)
         x_new, y_new, u_new, v_new, comm, obs = self._update_params(state, gx, gy)
         new = SRVRState(x_new, y_new, state.x, state.y, gx, gy, u_new, v_new,
                         gx, gy, state.step + 1, comm, obs)
         return new, _metrics(loss, gx, gy, x_new, y_new, u_new)
 
     def step(self, state: SRVRState, batch: Any):
-        loss, (gx_cur, gy_cur) = _euclid_grads(self.problem, state.x, state.y, batch)
-        _, (gx_old, gy_old) = _euclid_grads(self.problem, state.x_prev,
+        loss, (gx_cur, gy_cur) = _euclid_grads(self, state.x, state.y, batch)
+        _, (gx_old, gy_old) = _euclid_grads(self, state.x_prev,
                                             state.y_prev, batch)
         gx_est = jax.tree.map(lambda g, go, e: e + g - go,
                               gx_cur, gx_old, state.gx_est)

@@ -129,7 +129,7 @@ class DecentralizedGDA:
         DISTINCT buffers — the jitted step donates the whole state, and XLA
         rejects donating one buffer twice."""
         x0, y0 = _strong(x0), _strong(y0)
-        rgx, gy = jax.vmap(self.problem.rgrads)(x0, y0, batch0)
+        rgx, gy = self.backend.node_map(self.problem.rgrads)(x0, y0, batch0)
         comm0 = comms_layer.maybe_init_state(
             self.engine, {"x": x0, "y": y0, "u": rgx, "v": gy})
         obs0 = self.telemetry.init_counters() if self.telemetry else None
@@ -171,7 +171,7 @@ class DecentralizedGDA:
 
         # ---- steps 6/7: gradient tracking ----------------------------------
         (loss_new, (rgx_new, gy_new)) = _vmapped_loss_and_rgrads(
-            self.problem, x_new, y_new, batch)
+            self.problem, x_new, y_new, batch, self.backend.node_map)
 
         u_new = jax.tree.map(lambda mu, g, gp: mu + g - gp,
                              mix("u", state.u, k), rgx_new, state.gx_prev)
@@ -273,13 +273,14 @@ def _strong(tree: PyTree) -> PyTree:
                         tree)
 
 
-def _vmapped_loss_and_rgrads(problem: MinimaxProblem, x, y, batch):
+def _vmapped_loss_and_rgrads(problem: MinimaxProblem, x, y, batch,
+                             node_map=jax.vmap):
     def one(xi, yi, bi):
         loss, (gx, gy) = jax.value_and_grad(problem.loss_fn, argnums=(0, 1))(xi, yi, bi)
         rgx = jax.tree.map(lambda m, xl, gl: m.tangent_project(xl, gl),
                            problem.manifold_map, xi, gx)
         return loss, (rgx, gy)
-    return jax.vmap(one)(x, y, batch)
+    return node_map(one)(x, y, batch)
 
 
 def _tree_mean_norm(tree: PyTree) -> Array:

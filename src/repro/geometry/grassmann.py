@@ -32,13 +32,13 @@ Array = jax.Array
 
 def horizontal_project(x: Array, g: Array) -> Array:
     """P_{H_x}(g) = g - x (x^T g): projection onto the horizontal space."""
-    xtg = jnp.einsum("...dr,...ds->...rs", x, g)
-    return g - jnp.einsum("...dr,...rs->...ds", x, xtg)
+    xtg = S.mm("...dr,...ds->...rs", x, g)
+    return g - S.mm("...dr,...rs->...ds", x, xtg)
 
 
 def principal_angles(x: Array, y: Array) -> Array:
     """Principal angles between span(x) and span(y) (ascending, in [0, pi/2])."""
-    s = jnp.linalg.svd(jnp.einsum("...dr,...ds->...rs", x, y),
+    s = jnp.linalg.svd(S.mm("...dr,...ds->...rs", x, y),
                        compute_uv=False)
     return jnp.arccos(jnp.clip(s, -1.0, 1.0))[..., ::-1]
 

@@ -47,13 +47,13 @@ def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (bq, bkv)
 
-    qp = qpos_ref[0]                                      # (bq,)  int32
-    kp = kpos_ref[0]                                      # (bkv,) int32
-    mask = (kp >= 0)[None, :]
+    qp = qpos_ref[0]                                      # (bq, 1)  int32
+    kp = kpos_ref[0]                                      # (1, bkv) int32
+    mask = kp >= 0
     if causal:
-        mask &= kp[None, :] <= qp[:, None]
+        mask &= kp <= qp
     if window is not None:
-        mask &= (qp[:, None] - kp[None, :]) < window
+        mask &= (qp - kp) < window
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_ref[...]                                   # (bq, 1)
@@ -86,9 +86,12 @@ def flash_attention_bhsd(q: Array, k: Array, v: Array,
                          interpret: bool = False) -> Array:
     """q: (B, H, S, hd); k/v: (B, Hkv, T, hd); positions (B, S)/(B, T).
 
-    S and T must be multiples of the block sizes (ops.py pads); hd should be
-    a multiple of 128 for MXU alignment on real hardware (any hd works in
-    interpret mode).
+    S and T must be multiples of the block sizes (ops.py pads).  On the
+    chip every block's last two dims must divide by (8, 128) or span the
+    array: ``block_q`` by 8 and ``block_kv`` by 128 unless either covers
+    its whole (padded) sequence; hd is always a full dim.  Positions are
+    laid out as a (B, S, 1) column and a (B, 1, T) row so their blocks
+    obey the same rule and the mask needs no in-kernel transpose.
     """
     b, h, s_len, hd = q.shape
     hkv, t_len = k.shape[1], k.shape[2]
@@ -108,8 +111,8 @@ def flash_attention_bhsd(q: Array, k: Array, v: Array,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b_, h_, i, j: (b_, i)),
-            pl.BlockSpec((1, block_kv), lambda b_, h_, i, j: (b_, j)),
+            pl.BlockSpec((1, block_q, 1), lambda b_, h_, i, j: (b_, i, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda b_, h_, i, j: (b_, 0, j)),
             pl.BlockSpec((1, 1, block_q, hd), lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_kv, hd),
                          lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
@@ -126,4 +129,4 @@ def flash_attention_bhsd(q: Array, k: Array, v: Array,
         ],
         interpret=interpret,
         name="flash_attention",
-    )(q_positions, kv_positions, q, k, v)
+    )(q_positions[:, :, None], kv_positions[:, None, :], q, k, v)

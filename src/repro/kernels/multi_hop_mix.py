@@ -1,10 +1,11 @@
 """Fused multi-hop ring-gossip megakernel.
 
 One ``pallas_call`` executes the *entire* local work of a k-hop ``W^k``
-ring schedule.  The hop-by-hop ``ShardMapBackend`` path pays k ppermute
-launches plus k combine launches per mix; the bench shows that launch
-latency — not bytes — is what loses to the stacked backend (127 vs 998
-hops/sec at 64k params/node).  This kernel collapses the schedule:
+ring schedule (the int8 variant takes one per hop, see below).  The
+hop-by-hop ``ShardMapBackend`` path pays k ppermute launches plus k
+combine launches per mix; the bench shows that launch latency — not
+bytes — is what loses to the stacked backend (127 vs 998 hops/sec at 64k
+params/node).  This kernel collapses the schedule:
 
 halo formulation
   The caller (``ShardMapBackend._gather_halo``) prepends/appends ``halo``
@@ -25,12 +26,21 @@ halo formulation
   ``test_mix_backend_equiv.py`` intact.  (The pyramid also does only the
   row work that can reach the center — no combines on panel-end garbage.)
 
+panel layout
+  A node row is a whole flattened model (~1e8 floats) while a panel has
+  only ``b + 2*halo`` rows.  Padding those rows to the chip's sublane tile
+  (8 f32 / 32 int8 rows) would multiply the panel's memory by up to 8x,
+  so both kernels view the ``(rows, F)`` panel as ``(rows, F / 128,
+  128)``: rows become an untiled leading dim and each row's features fill
+  whole tiles (``ops.py`` pads the feature tail to :data:`F32_TILE` /
+  :data:`INT8_TILE` elements).  Hops slice the leading dim.
+
 fp32 variant (``multi_hop_mix_flat``)
   Single-pass grid over feature blocks: the panel's rows all fit one block
-  (``b + 2*halo`` is small), so each grid step loads a ``(rows, block_f)``
-  tile, runs every hop in VMEM, and writes only the ``out_rows`` center
-  rows — one panel read + one block write total, versus 2k HBM round
-  trips for the unfused schedule.
+  (``b + 2*halo`` is small), so each grid step loads a ``(rows,
+  block_f / 128, 128)`` tile, runs every hop in VMEM, and writes only the
+  ``out_rows`` center rows — one panel read + one block write total,
+  versus 2k HBM round trips for the unfused schedule.
 
 int8 variant (``multi_hop_mix_quant_flat``)
   The all-hop compressed schedule: the panel arrives as int8 payloads with
@@ -38,11 +48,11 @@ int8 variant (``multi_hop_mix_quant_flat``)
   dequantize + combine, and every later hop *re-quantizes* its input
   deterministically (round-to-nearest, per-row max-abs/127 scale — the
   values a receiver would have decoded had that hop's rows been shipped as
-  int8).  Per-row maxima need the full row, so this variant uses the
-  two-pass revisiting-grid trick from ``retract.py``: the f32 state lives
-  in the output ref (revisited per stage), a max-accumulate stage reduces
-  row maxima into VMEM scratch across feature blocks, and the following
-  stage requantizes + combines.  Quantization math is kept expression-
+  int8).  Per-row maxima need the whole row, and a TPU kernel never reads
+  back an output block it has already written, so the schedule is one
+  launch per hop over ``(rows, block_f / 128, 128)`` blocks: each launch
+  writes its f32 panel and, fused in, the per-block row maxima from which
+  the next hop's scales come.  Quantization math is kept expression-
   identical to ``comms.compress.quantize_det`` so the stacked backend's
   hop-by-hop oracle decodes the same int8 values at every hop (results
   agree to FMA rounding of the final combines).
@@ -57,11 +67,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-DEFAULT_BLOCK_F = 1024
+#: feature elements of one native tile per row: f32 (8, 128), int8 (32, 128)
+F32_TILE = 8 * 128
+INT8_TILE = 32 * 128
+DEFAULT_BLOCK_F = F32_TILE
+DEFAULT_BLOCK_F_QUANT = INT8_TILE
 _EPS = 1e-12   # same scale floor as comms.compress
 
 
@@ -103,6 +116,7 @@ def _hop_dq(q: Array, s: Array, wc: float, ws: float) -> Array:
 
 def _mhm_kernel(x_ref, o_ref, *, hops: int, halo: int, w_self: float,
                 w_side: float):
+    # refs are (rows, nb, 128) / (out_rows, nb, 128): hops slice dim 0
     z = x_ref[...].astype(jnp.float32)
     for _ in range(hops):
         z = _hop(z, w_self, w_side)
@@ -118,103 +132,99 @@ def multi_hop_mix_flat(panel: Array, *, hops: int, out_rows: int, halo: int,
                        w_self: float, w_side: float,
                        block_f: int = DEFAULT_BLOCK_F,
                        interpret: bool = False) -> Array:
-    """``hops`` fused ring combines on a ``(halo + b + halo [+ pad], F)``
-    panel; returns the ``(out_rows, F)`` center rows.  ``F % block_f == 0``
-    (ops.py pads); requires ``halo >= hops`` for exact output."""
+    """``hops`` fused ring combines on a ``(halo + b + halo, F)`` panel;
+    returns the ``(out_rows, F)`` center rows.  ``F % block_f == 0`` and
+    ``block_f`` is a multiple of 128 (ops.py pads); on the chip
+    ``block_f / 128`` must also divide by 8 unless one block spans the
+    row.  Requires ``halo >= hops`` for exact output."""
     rows, f = panel.shape
     block_f = min(block_f, f)
-    if f % block_f:
+    if f % block_f or block_f % 128:
         raise ValueError(f"multi_hop_mix_flat: F={f} not a multiple of "
-                         f"block_f={block_f}; pad the lane tail "
-                         f"(ops.multi_hop_mix does)")
+                         f"block_f={block_f} (a multiple of 128); pad the "
+                         f"lane tail (ops.multi_hop_mix does)")
     kernel = functools.partial(_mhm_kernel, hops=hops, halo=halo,
                                w_self=w_self, w_side=w_side)
-    return pl.pallas_call(
+    nb = block_f // 128
+    out = pl.pallas_call(
         kernel,
         grid=(f // block_f,),
-        in_specs=[pl.BlockSpec((rows, block_f), lambda j: (0, j))],
-        out_specs=pl.BlockSpec((out_rows, block_f), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((out_rows, f), panel.dtype),
+        in_specs=[pl.BlockSpec((rows, nb, 128), lambda j: (0, j, 0))],
+        out_specs=pl.BlockSpec((out_rows, nb, 128), lambda j: (0, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((out_rows, f // 128, 128),
+                                       panel.dtype),
         interpret=interpret,
         name="multi_hop_mix",
-    )(panel)
+    )(panel.reshape(rows, f // 128, 128))
+    return out.reshape(out_rows, f)
 
 
 # ---------------------------------------------------------------------------
-# int8 all-hop megakernel — revisiting grid, per-hop requantization
+# int8 all-hop schedule — one launch per hop, row maxima fused in
 # ---------------------------------------------------------------------------
 
 
-def _mhmq_kernel(q_ref, s_ref, state_ref, mx_ref, sc_ref, *, hops: int,
+def _mhmq_kernel(x_ref, s_ref, z_ref, mx_ref, *, requant: bool,
                  w_self: float, w_side: float):
-    """Stages over ``program_id(0)``: stage 0 dequantizes the wire payload
-    and runs hop 0; each later hop is a (max-accumulate, requantize +
-    combine) stage pair.  The f32 evolving panel lives in ``state_ref``
-    (the output, revisited every stage); the caller slices the center rows.
-    """
-    p = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-    del hops  # schedule length is encoded in the grid
-
-    @pl.when(p == 0)
-    def _hop0():
-        state_ref[...] = _hop_dq(q_ref[...].astype(jnp.float32),
-                                 s_ref[...].astype(jnp.float32),
-                                 w_self, w_side)
-
-    @pl.when(p % 2 == 1)
-    def _row_max():
-        @pl.when(j == 0)
-        def _reset():
-            mx_ref[...] = jnp.zeros_like(mx_ref)
-
-        m = jnp.max(jnp.abs(state_ref[...]), axis=1, keepdims=True)
-        mx_ref[...] = jnp.maximum(mx_ref[...],
-                                  jnp.broadcast_to(m, mx_ref.shape))
-
-        @pl.when(j == nj - 1)
-        def _finalize_scale():
-            sc_ref[...] = jnp.maximum(mx_ref[...] / 127.0, _EPS)
-
-    @pl.when((p >= 2) & (p % 2 == 0))
-    def _requant_combine():
-        scale = sc_ref[...][:, :1]                       # (rows, 1)
+    """One hop on a ``(rows, nb, 128)`` block: (requantize with the row
+    scales ``(rows, 1, 128)`` when ``requant``), dequantize + combine, and
+    emit the block's per-row, per-lane ``|z|`` maxima for the next hop's
+    scales."""
+    x = x_ref[...].astype(jnp.float32)
+    s = s_ref[...]
+    if requant:
         # rounded values are integers, exact in f32 — no int8 cast needed
-        q = jnp.clip(jnp.round(state_ref[...] / scale), -127.0, 127.0)
-        state_ref[...] = _hop_dq(q, scale, w_self, w_side)
+        x = jnp.clip(jnp.round(x / s), -127.0, 127.0)
+    z = _hop_dq(x, s, w_self, w_side)
+    z_ref[...] = z
+    mx_ref[0] = jnp.max(jnp.abs(z), axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("hops", "w_self", "w_side",
                                              "block_f", "interpret"))
 def multi_hop_mix_quant_flat(q_panel: Array, s_panel: Array, *, hops: int,
                              w_self: float, w_side: float,
-                             block_f: int = DEFAULT_BLOCK_F,
+                             block_f: int = DEFAULT_BLOCK_F_QUANT,
                              interpret: bool = False) -> Array:
     """All-hop compressed schedule on an int8 ``(rows, F)`` halo panel with
     per-row f32 scales ``(rows, 1)``.  Returns the full f32 ``(rows, F)``
-    evolved panel (callers slice the center rows) — the panel is the
-    kernel's cross-stage state, so it is the natural output shape."""
+    evolved panel (callers slice the center rows).  ``F % block_f == 0``
+    and ``block_f`` is a multiple of 128 (ops.py pads); on the chip
+    ``block_f / 128`` must also be a multiple of 32 (the int8 tile height)
+    unless one block spans the row."""
     rows, f = q_panel.shape
     block_f = min(block_f, f)
-    if f % block_f:
+    if f % block_f or block_f % 128:
         raise ValueError(f"multi_hop_mix_quant_flat: F={f} not a multiple "
-                         f"of block_f={block_f}; pad the lane tail "
-                         f"(ops.multi_hop_mix_quant does)")
-    kernel = functools.partial(_mhmq_kernel, hops=hops, w_self=w_self,
-                               w_side=w_side)
-    q_spec = pl.BlockSpec((rows, block_f), lambda p, j: (0, j))
-    s_spec = pl.BlockSpec((rows, 1), lambda p, j: (0, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(2 * hops - 1, f // block_f),
-        in_specs=[q_spec, s_spec],
-        out_specs=pl.BlockSpec((rows, block_f), lambda p, j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((rows, f), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((rows, 128), jnp.float32),   # per-row |z| max acc
-            pltpu.VMEM((rows, 128), jnp.float32),   # finalized scales
-        ],
-        interpret=interpret,
-        name="multi_hop_mix_quant",
-    )(q_panel, s_panel)
+                         f"of block_f={block_f} (a multiple of 128); pad "
+                         f"the lane tail (ops.multi_hop_mix_quant does)")
+    nb, n_blocks = block_f // 128, f // block_f
+    x_spec = pl.BlockSpec((rows, nb, 128), lambda j: (0, j, 0))
+    s_spec = pl.BlockSpec((rows, 1, 128), lambda j: (0, 0, 0))
+    mx_spec = pl.BlockSpec((1, rows, 1, 128), lambda j: (j, 0, 0, 0))
+    out_shape = [jax.ShapeDtypeStruct((rows, f // 128, 128), jnp.float32),
+                 jax.ShapeDtypeStruct((n_blocks, rows, 1, 128), jnp.float32)]
+
+    def hop(x, s, requant):
+        return pl.pallas_call(
+            functools.partial(_mhmq_kernel, requant=requant, w_self=w_self,
+                              w_side=w_side),
+            grid=(n_blocks,),
+            in_specs=[x_spec, s_spec],
+            out_specs=[x_spec, mx_spec],
+            out_shape=out_shape,
+            interpret=interpret,
+            name="multi_hop_mix_quant",
+        )(x, s)
+
+    def lanes(scale):                        # (rows, 1) -> (rows, 1, 128)
+        return jnp.broadcast_to(scale.reshape(rows, 1, 1), (rows, 1, 128))
+
+    z = q_panel.reshape(rows, f // 128, 128)
+    s = lanes(s_panel.astype(jnp.float32))
+    for h in range(hops):
+        z, mx = hop(z, s, requant=h > 0)
+        # the row max is exact in any order, so this scale is bitwise the
+        # oracle's max-abs / 127 over the whole row
+        s = lanes(jnp.maximum(jnp.max(mx, axis=(0, 3)) / 127.0, _EPS))
+    return z.reshape(rows, f)

@@ -17,6 +17,7 @@ Launch configs (block shapes, NS iteration counts) resolve through
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -76,6 +77,9 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
     (B, S, T, H, hd, dtype) key when one is cached (see ``kernels/tune.py``;
     on the ref path the tuned ``block_kv`` drives the streaming chunk), else
     the hand-picked module defaults; explicit values always win.
+
+    Differentiable on every path: the Pallas paths carry a ``custom_vjp``
+    whose backward is the VJP of ``ref.blockwise_attention``.
     """
     impl = impl or _default_impl()
     tuned = {}
@@ -110,25 +114,62 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
         q_positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     if kv_positions is None:
         kv_positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
+    return _flash_pallas(q, k, v, q_positions.astype(jnp.int32),
+                         kv_positions.astype(jnp.int32), causal, window,
+                         softmax_scale, block_q, block_kv,
+                         impl == "pallas_interpret")
 
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash_pallas(q, k, v, q_positions, kv_positions, causal, window,
+                  softmax_scale, block_q, block_kv, interpret):
+    """The Pallas forward at the public (B, S, H, hd) layout.
+
+    Its backward is the VJP of ``ref.blockwise_attention`` (the streaming
+    jnp oracle, same semantics) evaluated at the saved q/k/v: XLA compiles
+    it on every backend, so ``jax.grad`` never differentiates the
+    ``pallas_call`` itself.
+    """
+    s, t = q.shape[1], k.shape[1]
     qt = jnp.swapaxes(q, 1, 2)           # (B, H, S, hd)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     qt, pad_q = _pad_to(qt, 2, min(block_q, max(s, 1)))
     kt, pad_kv = _pad_to(kt, 2, min(block_kv, max(t, 1)))
     vt, _ = _pad_to(vt, 2, min(block_kv, max(t, 1)))
-    qp = jnp.pad(q_positions.astype(jnp.int32), ((0, 0), (0, qt.shape[2] - s)),
+    qp = jnp.pad(q_positions, ((0, 0), (0, qt.shape[2] - s)),
                  constant_values=0)
-    kp = jnp.pad(kv_positions.astype(jnp.int32), ((0, 0), (0, kt.shape[2] - t)),
+    kp = jnp.pad(kv_positions, ((0, 0), (0, kt.shape[2] - t)),
                  constant_values=-1)
 
     out = _fa.flash_attention_bhsd(
         qt, kt, vt, qp, kp, causal=causal, window=window,
         softmax_scale=softmax_scale,
         block_q=min(block_q, qt.shape[2]), block_kv=min(block_kv, kt.shape[2]),
-        interpret=(impl == "pallas_interpret"))
+        interpret=interpret)
     out = jnp.swapaxes(out, 1, 2)
     return out[:, :s]
+
+
+def _flash_pallas_fwd(q, k, v, q_positions, kv_positions, causal, window,
+                      softmax_scale, block_q, block_kv, interpret):
+    out = _flash_pallas(q, k, v, q_positions, kv_positions, causal, window,
+                        softmax_scale, block_q, block_kv, interpret)
+    return out, (q, k, v, q_positions, kv_positions)
+
+
+def _flash_pallas_bwd(causal, window, softmax_scale, block_q, block_kv,
+                      interpret, res, g):
+    q, k, v, q_positions, kv_positions = res
+    _, vjp = jax.vjp(
+        lambda q, k, v: ref.blockwise_attention(
+            q, k, v, causal=causal, window=window, q_positions=q_positions,
+            kv_positions=kv_positions, softmax_scale=softmax_scale),
+        q, k, v)
+    return (*vjp(g), None, None)
+
+
+_flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -233,6 +274,10 @@ def fused_retract(x: Array, g: Array, *, ns_iters: int | None = None,
     ``ns_iters`` / ``block_d`` default to the tuned config for this
     (d, r, dtype) when one is cached (see ``kernels/tune.py``), else the
     hand-picked defaults; explicit values always win.
+
+    The Pallas paths hold the whole (r, r) algebra in VMEM, so they raise
+    ``ValueError`` where ``retract.vmem_bytes`` exceeds the chip's scoped
+    limit (r past 512 after lane padding, e.g. 576 x 576).
     """
     impl = impl or _default_impl()
     d, r = x.shape[-2:]
@@ -249,18 +294,24 @@ def fused_retract(x: Array, g: Array, *, ns_iters: int | None = None,
         return ref.fused_retract_ref(x, g, ns_iters=ns_iters)
 
     interpret = impl == "pallas_interpret"
+    d_p = d + (-d) % 128
+    block = min(block_d if d_p % block_d == 0 else 128, d_p)
+    need = _rt.vmem_bytes(r, block)
+    if need > _rt.VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"fused_retract at (d, r) = ({d}, {r}) needs about "
+            f"{need / 2**20:.1f} MiB of VMEM, over the "
+            f"{_rt.VMEM_LIMIT_BYTES / 2**20:.0f} MiB limit; use the unfused "
+            "retraction='polar' for this leaf")
 
     def one(xi: Array, gi: Array) -> Array:
-        d, r = xi.shape
         # pad r to the 128-lane boundary, d to a multiple of the block size;
         # zero padding is exact (see kernels/retract.py docstring)
         pr = (-r) % 128
-        pd = (-d) % 128
-        d_p = d + pd
-        block = block_d if d_p % block_d == 0 else 128
+        pd = d_p - d
         xi_p = jnp.pad(xi, ((0, pd), (0, pr)))
         gi_p = jnp.pad(gi, ((0, pd), (0, pr)))
-        out = _rt.fused_retract_2d(xi_p, gi_p, block_d=min(block, d_p),
+        out = _rt.fused_retract_2d(xi_p, gi_p, block_d=block,
                                    ns_iters=ns_iters, interpret=interpret)
         return out[:d, :r]
 
@@ -355,32 +406,30 @@ def quant_mix(q_self: Array, q_left: Array, q_right: Array,
             w_self=w_self, w_side=w_side, out_dtype=out_dtype)
         return out.reshape(q_self.shape)
 
+    # each row is (cols / 128, 128) lane rows: pad to whole lane rows, and
+    # past one int8 tile height (32) to whole (32, 128) tiles; padded
+    # elements carry q=0 and contribute 0
     cols = q_self.size // rows
-    pad_c = (-cols) % 128
-    cols_p = cols + pad_c
-    # int8 min tile is (32, 128): pad rows up to the sublane boundary so the
-    # compiled kernel tiles cleanly (padded rows carry q=0 -> contribute 0)
-    pad_r = (-rows) % 32
-    rows_p = rows + pad_r
+    n = -(-cols // 128)
+    if n > 32:
+        n += (-n) % 32
+    cols_p = 128 * n
 
     def flat(q):
-        qf = q.reshape(rows, -1)
-        return jnp.pad(qf, ((0, pad_r), (0, pad_c)))
+        return jnp.pad(q.reshape(rows, -1), ((0, 0), (0, cols_p - cols)))
 
-    scales = [jnp.pad(s, ((0, pad_r), (0, 0))) for s in scales]
-    tuned = _tune.lookup("quant_mix", (rows_p, cols_p), "int8") or {}
+    tuned = _tune.lookup("quant_mix", (rows, cols_p), "int8") or {}
     cands = ([tuned["block_cols"]] if "block_cols" in tuned else []) \
-        + [_qm.DEFAULT_BLOCK_COLS, 1024, 512, 256, 128]
-    block_c = cols_p
-    for cand in cands:
-        if cols_p % cand == 0:
-            block_c = cand
-            break
+        + [_qm.DEFAULT_BLOCK_COLS, 16384, 8192, 4096]
+    block_c = next((c for c in cands if cols_p % c == 0), cols_p)
+    # as many whole rows per step as keep the block near TARGET_BLOCK
+    block_r = max(d for d in range(1, rows + 1) if rows % d == 0
+                  and (d == 1 or d * block_c <= _qm.TARGET_BLOCK))
     out = _qm.quant_mix_2d(flat(q_self), flat(q_left), flat(q_right), *scales,
                            w_self=w_self, w_side=w_side, out_dtype=out_dtype,
-                           block_rows=32, block_cols=block_c,
+                           block_rows=block_r, block_cols=block_c,
                            interpret=(impl == "pallas_interpret"))
-    return out[:rows, :cols].reshape(q_self.shape)
+    return out[:, :cols].reshape(q_self.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -388,17 +437,17 @@ def quant_mix(q_self: Array, q_left: Array, q_right: Array,
 # ---------------------------------------------------------------------------
 
 
-def _pick_block_f(kernel: str, rows_p: int, f_p: int, dtype,
-                  hops: int, block_f: int | None) -> int:
+def _pick_block_f(kernel: str, rows: int, f_p: int, dtype,
+                  hops: int, block_f: int | None, default: int) -> int:
     """Feature-block width: explicit > tuned-for-this-key > the largest
     default candidate dividing the padded lane count (which is a multiple
     of 128, so the 128 fallback always divides)."""
     if block_f is not None:
         return block_f
-    tuned = _tune.lookup(kernel, (rows_p, f_p), str(dtype),
+    tuned = _tune.lookup(kernel, (rows, f_p), str(dtype),
                          extra={"hops": hops}) or {}
     cands = ([tuned["block_f"]] if "block_f" in tuned else []) \
-        + [_mh.DEFAULT_BLOCK_F, 4096, 2048, 512, 256, 128]
+        + [default, 4096, 2048, 1024, 512, 256, 128]
     for cand in cands:
         if f_p % cand == 0:
             return cand
@@ -410,10 +459,9 @@ def multi_hop_mix(panel: Array, *, hops: int, out_rows: int, halo: int,
                   block_f: int | None = None) -> Array:
     """``hops`` fused ring combines on a halo panel ``(halo + b + halo, ...)``
     (trailing dims flattened); returns the exact ``(out_rows, ...)`` center
-    rows.  Requires ``halo >= hops``; both the lane tail and (for the
-    compiled kernel) the row tail are zero-padded — bottom-row padding is
-    exact because panel-end garbage advances one row per hop and never
-    reaches the center rows.
+    rows.  Requires ``halo >= hops``.  The feature tail is zero-padded to
+    whole (8, 128) f32 tiles; rows are never padded (the kernel keeps them
+    as an untiled leading dim, see ``kernels/multi_hop_mix.py``).
     """
     assert halo >= hops, (halo, hops)
     impl = impl or _default_impl()
@@ -427,12 +475,11 @@ def multi_hop_mix(panel: Array, *, hops: int, out_rows: int, halo: int,
                                     w_self=w_self, w_side=w_side)
         return out.reshape((out_rows,) + panel.shape[1:])
 
-    pad_f = (-f) % 128
-    pad_r = (-rows) % 8
-    p2 = jnp.pad(panel.reshape(rows, -1), ((0, pad_r), (0, pad_f)))
+    pad_f = (-f) % _mh.F32_TILE
+    p2 = jnp.pad(panel.reshape(rows, -1), ((0, 0), (0, pad_f)))
     f_p = f + pad_f
-    block = _pick_block_f("multi_hop_mix", rows + pad_r, f_p, panel.dtype,
-                          hops, block_f)
+    block = _pick_block_f("multi_hop_mix", rows, f_p, panel.dtype,
+                          hops, block_f, _mh.DEFAULT_BLOCK_F)
     out = _mh.multi_hop_mix_flat(p2, hops=hops, out_rows=out_rows, halo=halo,
                                  w_self=w_self, w_side=w_side, block_f=block,
                                  interpret=(impl == "pallas_interpret"))
@@ -463,14 +510,13 @@ def multi_hop_mix_quant(q_panel: Array, s_panel: Array, *, hops: int,
         return z[halo:halo + out_rows].astype(out_dtype) \
             .reshape((out_rows,) + q_panel.shape[1:])
 
-    # int8 min tile is (32, 128); padded q rows are zero -> dequantize to 0
-    pad_f = (-f) % 128
-    pad_r = (-rows) % 32
-    q2 = jnp.pad(q_panel.reshape(rows, -1), ((0, pad_r), (0, pad_f)))
-    s2 = jnp.pad(s2, ((0, pad_r), (0, 0)), constant_values=1.0)
+    # the feature tail pads to whole (32, 128) int8 tiles (zeros dequantize
+    # to 0 and never raise a row max); rows are never padded
+    pad_f = (-f) % _mh.INT8_TILE
+    q2 = jnp.pad(q_panel.reshape(rows, -1), ((0, 0), (0, pad_f)))
     f_p = f + pad_f
-    block = _pick_block_f("multi_hop_mix_quant", rows + pad_r, f_p, "int8",
-                          hops, block_f)
+    block = _pick_block_f("multi_hop_mix_quant", rows, f_p, "int8",
+                          hops, block_f, _mh.DEFAULT_BLOCK_F_QUANT)
     z = _mh.multi_hop_mix_quant_flat(q2, s2, hops=hops, w_self=w_self,
                                      w_side=w_side, block_f=block,
                                      interpret=(impl == "pallas_interpret"))

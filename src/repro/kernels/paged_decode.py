@@ -16,9 +16,11 @@ heads per kv head); pools ``(P, page_size, Hkv, hd)``; block table
 and are fully masked); seq_lens ``(S,)`` int32 — valid tokens including
 the current query token at position ``seq_lens - 1``.
 
-Grid: ``(S, Hkv, M // pages_per_block)`` with the page loop innermost —
-TPU grid execution is sequential there, so the (acc, m, l) VMEM scratch
+Grid: ``(S, M // pages_per_block)`` with the page loop innermost — TPU
+grid execution is sequential there, so the (acc, m, l) VMEM scratch
 persists across page steps exactly like ``flash_attention``'s kv loop.
+Each step fetches whole pages (all Hkv heads) and loops over the kv heads
+in the kernel body.
 ``pages_per_block`` fuses several page fetches per grid step (the tuned
 knob, see ``kernels/tune.py``) by passing the pool once per fused page
 with staggered index_maps.
@@ -49,7 +51,8 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, *refs, scale: float,
     o_ref = refs[2 * g_pages]
     acc_ref, m_ref, l_ref = refs[2 * g_pages + 1:]
     i = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
+    hkv = q_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
@@ -58,37 +61,39 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, *refs, scale: float,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     sl = sl_ref[i]                                       # valid tokens
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, hd)
-    k = jnp.concatenate([r[0, :, 0, :] for r in k_refs], axis=0) \
-        .astype(jnp.float32)                             # (g_pages*ps, hd)
-    v = jnp.concatenate([r[0, :, 0, :] for r in v_refs], axis=0) \
-        .astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (G, span)
-
     span = g_pages * page_size
     pos = j * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
     mask = pos < sl                                      # (1, span)
     if window is not None:
         # the query sits at position sl - 1
         mask &= (sl - 1 - pos) < window
-    s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]                                  # (G, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    # fully-masked spans (empty slots / dump pages): keep rows exactly zero
-    p = jnp.where(mask, p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    for kh in range(hkv):        # static: every kv head of the fetched pages
+        q = q_ref[0, kh].astype(jnp.float32) * scale     # (G, hd)
+        k = jnp.concatenate([r[0, :, kh, :] for r in k_refs], axis=0) \
+            .astype(jnp.float32)                         # (span, hd)
+        v = jnp.concatenate([r[0, :, kh, :] for r in v_refs], axis=0) \
+            .astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(mask, s, NEG_INF)                  # (G, span)
+
+        m_prev = m_ref[kh]                               # (G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        # fully-masked spans (empty slots / dump pages): keep rows exactly 0
+        p = jnp.where(mask, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[kh] = l_ref[kh] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[kh] = acc_ref[kh] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[kh] = m_new
 
     @pl.when(j == n_blocks - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -105,8 +110,7 @@ def paged_decode_shgd(q: Array, k_pages: Array, v_pages: Array,
     int32; seq_lens (S,) int32.  Returns (S, Hkv, G, hdv).
 
     ``M % pages_per_block == 0`` (ops.py pads the table with -1 columns);
-    hd should be a multiple of 128 for MXU alignment on real hardware
-    (any hd works in interpret mode).
+    the page size should divide by 8 (16 for bf16 pools) on the chip.
     """
     s_slots, hkv, group, hd = q.shape
     n_pages, ps, _, _ = k_pages.shape
@@ -117,20 +121,23 @@ def paged_decode_shgd(q: Array, k_pages: Array, v_pages: Array,
     n_blocks = m_pages // g
     scale = softmax_scale if softmax_scale is not None else hd ** -0.5
 
-    grid = (s_slots, hkv, n_blocks)
+    grid = (s_slots, n_blocks)
 
     def page_map(off):
         # scalar-prefetch index_map: clamp -1 (unallocated) to the dump
         # page 0 — those positions are >= seq_len and fully masked anyway
-        def index(i, kh, j, bt, sl):
-            return (jnp.maximum(bt[i, j * g + off], 0), 0, kh, 0)
+        def index(i, j, bt, sl):
+            return (jnp.maximum(bt[i, j * g + off], 0), 0, 0, 0)
         return index
 
-    in_specs = [pl.BlockSpec((1, 1, group, hd),
-                             lambda i, kh, j, bt, sl: (i, kh, 0, 0))]
-    in_specs += [pl.BlockSpec((1, ps, 1, hd), page_map(off))
+    # every block spans Hkv and hd whole: the chip's tiling rule (last two
+    # block dims divisible by (8, 128) or equal to the array's) then holds
+    # for any head count and head dim
+    in_specs = [pl.BlockSpec((1, hkv, group, hd),
+                             lambda i, j, bt, sl: (i, 0, 0, 0))]
+    in_specs += [pl.BlockSpec((1, ps, hkv, hd), page_map(off))
                  for off in range(g)]
-    in_specs += [pl.BlockSpec((1, ps, 1, hdv), page_map(off))
+    in_specs += [pl.BlockSpec((1, ps, hkv, hdv), page_map(off))
                  for off in range(g)]
 
     kernel = functools.partial(_paged_kernel, scale=scale, window=window,
@@ -139,12 +146,12 @@ def paged_decode_shgd(q: Array, k_pages: Array, v_pages: Array,
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, group, hdv),
-                               lambda i, kh, j, bt, sl: (i, kh, 0, 0)),
+        out_specs=pl.BlockSpec((1, hkv, group, hdv),
+                               lambda i, j, bt, sl: (i, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((group, hdv), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, hdv), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
+            pltpu.VMEM((hkv, group, 1), jnp.float32),
         ],
     )
     return pl.pallas_call(

@@ -46,6 +46,23 @@ Array = jax.Array
 DEFAULT_BLOCK_D = 256
 DEFAULT_NS_ITERS = 20
 
+#: the chip compiler's default scoped-VMEM limit for one kernel (v5e)
+VMEM_LIMIT_BYTES = 16 * 2**20
+
+
+def vmem_bytes(r: int, block_d: int) -> int:
+    """Scoped VMEM the compiled kernel needs at lane-padded rank ``r``.
+
+    Ten f32 (r, r) panels — the four scratch accumulators plus about six
+    live temporaries of the finalization and the Newton--Schulz loop — and
+    seven f32 (block_d, r) panels: x, g and the output, double-buffered,
+    plus one in-kernel temporary.  Fit to the v5e compiler's own figures:
+    within 0.6 MiB of them for r in 128..640 and block_d in 128..512 (at
+    r = 640, block_d = 256 it needs 20.6 MiB; at r = 256, 3.9 MiB).
+    """
+    r_p = r + (-r) % 128
+    return 4 * (10 * r_p * r_p + 7 * block_d * r_p)
+
 
 def _ns_invsqrt(a: Array, iters: int) -> Array:
     """Coupled Newton--Schulz inverse sqrt on an (r, r) VMEM value — the

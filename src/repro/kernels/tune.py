@@ -45,9 +45,9 @@ MODES = ("off", "load", "search")
 #: hand-picked launch configs (what ops.py shipped before the tuner)
 DEFAULTS = {
     "ring_mix": {"block_rows": 256},
-    "quant_mix": {"block_cols": 2048},
+    "quant_mix": {"block_cols": 32768},
     "multi_hop_mix": {"block_f": 1024},
-    "multi_hop_mix_quant": {"block_f": 1024},
+    "multi_hop_mix_quant": {"block_f": 4096},
     "fused_retract": {"block_d": 256, "ns_iters": 20},
     "flash_attention": {"block_q": 128, "block_kv": 128},
     "paged_decode": {"pages_per_block": 1},
@@ -57,11 +57,11 @@ DEFAULTS = {
 SPACES = {
     "ring_mix": [{"block_rows": v} for v in (512, 256, 128, 64, 32, 16, 8)],
     "quant_mix": [{"block_cols": v}
-                  for v in (4096, 2048, 1024, 512, 256, 128)],
+                  for v in (65536, 32768, 16384, 8192, 4096)],
     "multi_hop_mix": [{"block_f": v}
                       for v in (4096, 2048, 1024, 512, 256, 128)],
     "multi_hop_mix_quant": [{"block_f": v}
-                            for v in (4096, 2048, 1024, 512, 256, 128)],
+                            for v in (16384, 8192, 4096)],
     "fused_retract": [{"block_d": d, "ns_iters": n}
                       for n in (10, 12, 16, 20) for d in (128, 256, 512)],
     "flash_attention": [{"block_q": bq, "block_kv": bk}
@@ -344,7 +344,7 @@ def _default_for_shape(kernel: str, shape: tuple) -> dict:
         else:
             cfg["block_rows"] = rows
     if "block_cols" in cfg:
-        for cand in (cfg["block_cols"], 1024, 512, 256, 128):
+        for cand in (cfg["block_cols"], 16384, 8192, 4096):
             if f % cand == 0:
                 cfg["block_cols"] = cand
                 break

@@ -15,8 +15,10 @@ per-device payload each op moves over the interconnect once — a deliberate
 first-order model; ring reductions move ~2x, which we note rather than
 model).
 
-Pick the hardware with ``REPRO_HW=tpu_v4|tpu_v5e|tpu_v5p`` (or pass a
-:class:`HardwareModel` / registry name explicitly to the entry points);
+With a TPU attached the model is the chip's own (:func:`get_hardware`
+reads its ``device_kind``).  For CPU-side analysis pick one with
+``REPRO_HW=tpu_v4|tpu_v5e|tpu_v5p`` (or pass a :class:`HardwareModel` /
+registry name explicitly to the entry points);
 :func:`place` positions any :class:`repro.obs.Estimates` on that roofline.
 """
 from __future__ import annotations
@@ -45,7 +47,9 @@ class HardwareModel:
         return self.peak_flops / self.hbm_bw
 
 
-#: published per-chip peaks (bf16), keyed by the ``REPRO_HW`` names
+#: published per-chip peaks (bf16), keyed by the ``REPRO_HW`` names.
+#: Source: Google Cloud TPU documentation, system architecture pages for
+#: TPU v4, v5e and v5p.
 HARDWARE = {
     "tpu_v4": HardwareModel("tpu_v4", peak_flops=275e12, hbm_bw=1.2e12,
                             ici_bw=50e9),
@@ -55,12 +59,44 @@ HARDWARE = {
                              ici_bw=100e9),
 }
 
+#: ``device_kind`` as JAX reports it -> the :data:`HARDWARE` entry
+DEVICE_KINDS = {
+    "TPU v4": "tpu_v4",
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v5": "tpu_v5p",
+}
+
 DEFAULT_HW = "tpu_v5e"
 
 
+def hardware_for_kind(device_kind: str) -> HardwareModel:
+    """The model of an attached chip; an unknown kind is an error, never a
+    default."""
+    if device_kind not in DEVICE_KINDS:
+        raise ValueError(f"no hardware model for device_kind "
+                         f"{device_kind!r}; known: {sorted(DEVICE_KINDS)}")
+    return HARDWARE[DEVICE_KINDS[device_kind]]
+
+
 def get_hardware(name: Optional[str] = None) -> HardwareModel:
-    """Resolve a hardware model: explicit name > ``REPRO_HW`` env > v5e."""
-    name = name or os.environ.get("REPRO_HW") or DEFAULT_HW
+    """Resolve a hardware model.
+
+    With a TPU attached, the chip's own model (from its ``device_kind``);
+    an explicit ``name`` or ``REPRO_HW`` that names another chip is then an
+    error.  Without one (CPU-side analysis): explicit name > ``REPRO_HW``
+    env > v5e.
+    """
+    import jax
+
+    name = name or os.environ.get("REPRO_HW")
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        hw = hardware_for_kind(dev.device_kind)
+        if name and name != hw.name:
+            raise ValueError(f"hardware model {name!r} asked for, but the "
+                             f"attached chip is {hw.name}")
+        return hw
+    name = name or DEFAULT_HW
     if name not in HARDWARE:
         raise ValueError(f"unknown hardware model {name!r}; "
                          f"choose from {sorted(HARDWARE)}")
@@ -163,6 +199,20 @@ def top_collectives(hlo_text: str, n: int = 12) -> list[dict]:
             merged[key] = {**r, "count": 1, "total_bytes": r["bytes"]}
     out = sorted(merged.values(), key=lambda r: -r["total_bytes"])
     return out[:n]
+
+
+_KERNEL_RE = re.compile(
+    r"^\s*%?([A-Za-z_]\w*?)(?:\.\d+)?\s*=.*custom_call_target=\"tpu_custom_call\"",
+    re.M)
+
+
+def kernel_calls(hlo_text: str) -> dict[str, int]:
+    """Pallas kernels in compiled TPU HLO: ``tpu_custom_call`` instructions
+    counted by the kernel's ``name`` (which names the instruction)."""
+    out: dict[str, int] = {}
+    for name in _KERNEL_RE.findall(hlo_text):
+        out[name] = out.get(name, 0) + 1
+    return out
 
 
 @dataclasses.dataclass

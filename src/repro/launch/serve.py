@@ -1,8 +1,10 @@
 """Serving driver: batched autoregressive decode of a (consensus) model.
 
-On this CPU container it runs reduced configs for real (examples/
-serve_decode.py); on a TPU slice the same step functions are jitted against
-the production mesh (see dryrun.py for the lowering path).
+GQA architectures are served by the paged decode service; every other
+architecture by the contiguous-cache loop (``--legacy`` forces it).  The
+choice is made from the config before anything runs.  Reduced configs
+(``--smoke``) run on a CPU; on a TPU the full widths run with compiled
+Pallas kernels (``chip_smoke.py``).
 """
 from __future__ import annotations
 
@@ -14,8 +16,10 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_serve_step
 from repro.models import transformer as T
+from repro.serve import kv_cache
 
 
 def generate(cfg, params, prompt_tokens, n_new: int, *,
@@ -79,7 +83,8 @@ def _serve_engine(cfg, params, args) -> dict:
     n_tok = sum(len(r.tokens) for r in fin)
     return {
         "arch": cfg.name, "mode": "paged", "batch": args.batch,
-        "new_tokens": args.new_tokens, "wall_s": round(dt, 2),
+        "new_tokens": args.new_tokens, "tokens": n_tok,
+        "wall_s": round(dt, 2),
         "tok_per_s": round(n_tok / dt, 1),
         "sample": fin[0].tokens[:8],
     }
@@ -123,17 +128,26 @@ def main(argv=None) -> int:
                     help="force the contiguous-cache decode path")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = configs.get_config(args.arch, smoke=args.smoke)
+    paged = not args.legacy and _supports_paged(cfg)
     params = T.init_params(jax.random.PRNGKey(args.seed), cfg)
-    if args.legacy:
-        res = _serve_legacy(cfg, params, args)
+    if paged:
+        res = _serve_engine(cfg, params, args)
     else:
-        try:
-            res = _serve_engine(cfg, params, args)
-        except ValueError:      # non-GQA architecture: contiguous fallback
-            res = _serve_legacy(cfg, params, args)
+        res = _serve_legacy(cfg, params, args)
     print(json.dumps(res))
     return 0
+
+
+def _supports_paged(cfg) -> bool:
+    """Whether the paged service covers ``cfg`` (GQA attention only); the
+    contiguous-cache path serves every other architecture."""
+    try:
+        kv_cache.validate_config(cfg)
+    except ValueError:
+        return False
+    return True
 
 
 if __name__ == "__main__":

@@ -27,6 +27,7 @@ from repro import checkpoint, configs
 from repro.core.gda import GDAHyper
 from repro.core.metric import convergence_metric
 from repro.data.synthetic import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import TrainSpec, build_trainer, init_train_state
 from repro.obs import Telemetry
 
@@ -76,6 +77,7 @@ def main(argv=None) -> int:
                     help="elastic stale-hop tolerance (rounds)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     telemetry = None
     if args.telemetry:
         telemetry = Telemetry(

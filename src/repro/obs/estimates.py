@@ -140,16 +140,17 @@ def multi_hop_mix_est(rows: int, f: int, *, hops: int, out_rows: int,
     ``(out_rows, f)`` write — the unfused schedule's 2k HBM round trips
     collapse to ~1.  int8 all-hop: the payload arrives as 1 byte/element
     (+4 B/row scales), hop 0 adds 1 dequant mul/element, later hops add a
-    ~4 flop/element requant (div, round, clip, mul) and revisit the f32
-    state panel once per stage (max pass + combine pass)."""
+    ~4 flop/element requant (div, round, clip, mul); each hop is one
+    launch that reads the panel and writes the f32 state (the row maxima
+    ride along, negligible)."""
     n = float(rows) * f
     ops = 4.0 * hops * n
     if quant:
         ops += n + 4.0 * max(hops - 1, 0) * n       # dequant + requants
         in_bytes = 1.0 * n + 4.0 * rows
-        # state panel written at every combine stage, re-read at every
-        # max + requant stage (the revisiting-grid traffic)
-        lds = in_bytes + 4.0 * n * (3.0 * max(hops - 1, 0) + 1.0)
+        # hop 0 writes the f32 state; every later hop re-reads and
+        # rewrites it
+        lds = in_bytes + 4.0 * n * (2.0 * max(hops - 1, 0) + 1.0)
         mem = in_bytes + 4.0 * n
     else:
         in_bytes = float(itemsize) * n

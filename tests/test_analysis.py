@@ -152,6 +152,19 @@ def test_vmem_budget_fires_on_oversized_block():
     assert "exceeds" in findings[0].message
 
 
+@pytest.mark.parametrize("r,over", [(576, True), (192, False), (512, False)])
+def test_vmem_fused_retract_matches_chip_compiler(r, over):
+    """At d = 576 the v5e compiler refuses r = 576 (padded to 640 lanes)
+    and compiles r = 192; the model, the kernel's own budget check and the
+    wrapper's refusal all agree."""
+    hw = roofline.get_hardware("tpu_v5e")
+    findings = kernel_check.vmem_findings("fused_retract", {"block_d": 256},
+                                          dims={"r": r}, hw=hw)
+    assert bool(findings) == over
+    from repro.kernels import retract
+    assert (retract.vmem_bytes(r, 256) > retract.VMEM_LIMIT_BYTES) == over
+
+
 def test_vmem_footprint_scales_with_config():
     small = kernel_check.vmem_footprint("ring_mix", {}, {"block_rows": 8})
     big = kernel_check.vmem_footprint("ring_mix", {}, {"block_rows": 512})

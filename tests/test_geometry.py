@@ -107,6 +107,36 @@ def test_consensus_mean_and_dist(name):
     assert float(m.dist(xhat, x)) < 0.1
 
 
+@pytest.mark.parametrize("name", ["stiefel", "grassmann"])
+def test_manifold_algebra_runs_at_highest_precision(name):
+    """Every matmul of the retraction, projection and feasibility check is
+    asked for at HIGHEST precision: a TPU's default (one bf16 pass) leaves
+    x^T x off by ~0.1 at smollm widths."""
+    m = G.REGISTRY[name]
+    x = m.rand(jax.random.PRNGKey(0), 64, 16)
+    u = m.tangent_project(x, jax.random.normal(jax.random.PRNGKey(1),
+                                                (64, 16)))
+
+    def step(x, u):
+        y = m.retract(x, m.tangent_project(x, u))
+        return y, m.check(y)
+
+    jaxpr = jax.make_jaxpr(step)(x, u)
+    dots = []
+
+    def walk(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "dot_general":
+                dots.append(eqn.params["precision"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert dots
+    hi = jax.lax.Precision.HIGHEST
+    assert all(p == (hi, hi) for p in dots), dots
+
+
 def test_cayley_any_step_size_stays_feasible():
     """The CG normal-equation solve converges for ANY ||u|| (the Neumann
     fixed point needs ||u|| < 1 and documents so)."""

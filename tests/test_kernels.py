@@ -69,6 +69,48 @@ def test_flash_attention_ring_cache_positions():
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+GRAD_CASES = [
+    # b, s, h, hkv, hd, window, block
+    (1, 64, 4, 2, 32, None, 32),        # GQA, block-aligned
+    (2, 50, 4, 1, 16, None, 32),        # ragged: padded to 64 in the wrapper
+    (1, 40, 2, 2, 16, 12, 16),          # sliding window, ragged
+]
+
+
+@pytest.mark.parametrize("case", GRAD_CASES)
+def test_flash_attention_grads_vs_ref(case):
+    """``jax.value_and_grad`` through the Pallas path (its custom VJP) ==
+    through the jnp oracle: loss and q/k/v gradients, padded tails
+    included."""
+    b, s, h, hkv, hd, window, block = case
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q = jax.random.normal(ks[0], (b, s, h, hd))
+    k = jax.random.normal(ks[1], (b, s, hkv, hd))
+    v = jax.random.normal(ks[2], (b, s, hkv, hd))
+    w = jax.random.normal(ks[3], (b, s, h, hd))
+
+    def run(impl):
+        def loss(q, k, v):
+            out = ops.flash_attention(q, k, v, window=window, impl=impl,
+                                      block_q=block, block_kv=block)
+            return jnp.sum(out * w)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+    (l_got, g_got), (l_want, g_want) = run("pallas_interpret"), run("ref")
+    np.testing.assert_allclose(l_got, l_want, rtol=1e-5)
+    for got, want in zip(g_got, g_want):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_fused_retract_refuses_over_vmem():
+    """(576, 576) pads to 640 lanes: over the scoped VMEM budget, so the
+    Pallas paths raise instead of failing in the chip's compiler."""
+    x = jnp.zeros((576, 576))
+    with pytest.raises(ValueError, match="VMEM"):
+        ops.fused_retract(x, x, impl="pallas_interpret")
+
+
 # ---------------------------------------------------------------------------
 # stiefel projection
 # ---------------------------------------------------------------------------

@@ -329,6 +329,38 @@ def test_hardware_model_selection(monkeypatch):
     assert roofline.PEAK_FLOPS == hw.peak_flops    # legacy constants track
 
 
+def test_hardware_model_from_device_kind(monkeypatch):
+    """With a TPU attached the chip's own model is used, looked up by its
+    ``device_kind``; an unknown kind, or a name for another chip, is an
+    error rather than a default."""
+    import types
+
+    from repro.launch import roofline
+
+    assert roofline.hardware_for_kind("TPU v5 lite").name == "tpu_v5e"
+    assert roofline.hardware_for_kind("TPU v4").name == "tpu_v4"
+    with pytest.raises(ValueError, match="device_kind"):
+        roofline.hardware_for_kind("TPU v99")
+
+    def attach(kind):
+        dev = types.SimpleNamespace(platform="tpu", device_kind=kind)
+        monkeypatch.setattr(jax, "devices", lambda *a, **k: [dev])
+
+    monkeypatch.delenv("REPRO_HW", raising=False)
+    attach("TPU v5 lite")
+    assert roofline.get_hardware().name == "tpu_v5e"
+    assert roofline.get_hardware("tpu_v5e").name == "tpu_v5e"
+    with pytest.raises(ValueError, match="attached chip"):
+        roofline.get_hardware("tpu_v4")
+    monkeypatch.setenv("REPRO_HW", "tpu_v5p")
+    with pytest.raises(ValueError, match="attached chip"):
+        roofline.get_hardware()
+    monkeypatch.delenv("REPRO_HW")
+    attach("TPU v99")
+    with pytest.raises(ValueError, match="device_kind"):
+        roofline.get_hardware()
+
+
 def test_roofline_place_classifies_bound():
     from repro.launch import roofline
 
@@ -386,3 +418,35 @@ def test_bench_summary_append(tmp_path, monkeypatch):
     assert rows[0]["git_rev"] == "abc"
     assert rows[0]["us_per_call"] == 123.4
     assert "timestamp" in rows[0]
+
+
+def test_bench_harness_fails_on_entry_error(monkeypatch, capsys):
+    """Every entry runs and is reported; any failure fails the run."""
+    from benchmarks import run as bench_run
+
+    def boom():
+        raise RuntimeError("broken entry")
+
+    ran = []
+    monkeypatch.setattr(bench_run, "ALL", {"boom": boom,
+                                           "ok": lambda: ran.append(1)
+                                           or (1.0, "x=1")})
+    monkeypatch.setattr(bench_run, "append_summary", lambda *a, **k: None)
+    monkeypatch.setattr(sys, "argv", ["run.py", "boom", "ok"])
+    assert bench_run.main() == 1
+    assert ran == [1]
+    assert "boom,nan,ERROR:RuntimeError:broken entry" in capsys.readouterr().out
+    monkeypatch.setattr(sys, "argv", ["run.py", "ok"])
+    assert bench_run.main() == 0
+
+
+def test_mix_bench_refuses_tpu_instead_of_spawning(monkeypatch):
+    from benchmarks import mix_backend
+
+    def no_spawn(*a, **k):
+        raise AssertionError("spawned a worker process")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mix_backend.subprocess, "run", no_spawn)
+    with pytest.raises(RuntimeError, match="holds a TPU"):
+        mix_backend.run()

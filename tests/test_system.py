@@ -1,10 +1,12 @@
 """End-to-end system tests: real multi-step decentralized minimax training
 on CPU (reduced configs), serving loop, and the launchers' CLIs."""
 import json
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro import configs
 from repro.core.metric import convergence_metric
@@ -104,3 +106,55 @@ def test_serve_cli_smoke(capsys):
     rc = serve_cli.main(["--arch", "smollm-135m", "--smoke", "--batch", "2",
                          "--prompt-len", "8", "--new-tokens", "4"])
     assert rc == 0
+
+
+def test_serve_cli_takes_paged_path_for_gqa(capsys):
+    from repro.launch import serve as serve_cli
+    assert serve_cli._supports_paged(
+        configs.get_config("smollm-135m", smoke=True))
+    assert not serve_cli._supports_paged(
+        configs.get_config("xlstm-1.3b", smoke=True))
+    rc = serve_cli.main(["--arch", "smollm-135m", "--smoke", "--batch", "2",
+                         "--prompt-len", "8", "--new-tokens", "4"])
+    assert rc == 0
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert res["mode"] == "paged" and res["tokens"] == 2 * 4
+
+
+def test_serve_cli_paged_failure_propagates(monkeypatch):
+    """A ValueError inside the paged run (as a kernel the chip's compiler
+    refuses raises) is an error, not a cue to switch decode paths."""
+    from repro.launch import serve as serve_cli
+
+    def broken(*a, **k):
+        raise ValueError("kernel refused")
+
+    def legacy(*a, **k):
+        raise AssertionError("fell back to the contiguous-cache path")
+
+    monkeypatch.setattr(serve_cli, "_serve_engine", broken)
+    monkeypatch.setattr(serve_cli, "_serve_legacy", legacy)
+    with pytest.raises(ValueError, match="kernel refused"):
+        serve_cli.main(["--arch", "smollm-135m", "--smoke", "--batch", "2",
+                        "--prompt-len", "8", "--new-tokens", "4"])
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; otherwise the
+    cache sits at one fixed path in the checkout."""
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_compile_cache()
+        assert got == str(compile_cache.DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == got
+        assert compile_cache.DEFAULT_DIR.parent == \
+            Path(__file__).resolve().parents[1]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
