@@ -6,8 +6,12 @@ optimizer tests) is run three ways:
 * **off** — ``telemetry=None``: the pre-obs program;
 * **on**  — counters threaded + io_callback flush every FLUSH_EVERY steps;
 * **phases** — the same step split into separately-jitted compute / retract
-  / mix / metric pieces, timed per phase (in-jit phase timing is impossible;
-  this is the step-time breakdown §Telemetry reports).
+  / mix / metric pieces, timed per phase (the step-time breakdown
+  §Telemetry reports).  Split programs are not the fused step: the fused
+  step's own phases are its device scopes (``gda.grad``, ``gda.retract``,
+  ``gda.track``, ``gda.mix``, ``gda.metrics``, ``repro.obs.trace.scope``),
+  read from a profiler trace of the chip by ``bench/scopes.py``; what
+  becomes of these split programs is ROADMAP Design 10.
 
 Checks performed (all land in experiments/bench/obs.json):
 

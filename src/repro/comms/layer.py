@@ -48,6 +48,7 @@ from repro.comms.compress import (Int8Stochastic, compress_tree,
                                   make_compressor, tree_bits,
                                   tree_param_count)
 from repro.comms.spec import CommSpec
+from repro.obs import trace as obs_trace
 
 Array = jax.Array
 PyTree = Any
@@ -307,8 +308,9 @@ def make_mixer(gossip, engine: Optional[CommEngine],
 
     Returns ``(mix, finalize)``: ``mix(slot, tree, steps)`` routes through
     the comms engine when one is configured (threading the CommState) and
-    through the exact path otherwise; ``finalize()`` yields the CommState to
-    store in the next optimizer state.  ``backend`` overrides how exact hops
+    through the exact path otherwise, on every backend inside the device
+    scope ``gda.mix``; ``finalize()`` yields the CommState to store in the
+    next optimizer state.  ``backend`` overrides how exact hops
     execute (an engine carries its own backend); default is the gossip
     spec's resolved backend.
     """
@@ -316,10 +318,11 @@ def make_mixer(gossip, engine: Optional[CommEngine],
     exact = backend if backend is not None else resolve_backend(gossip)
 
     def mix(slot: str, tree: PyTree, steps: int) -> PyTree:
-        if engine is None:
-            return exact.mix(gossip, tree, steps)
-        out, box["cs"] = engine.mix(box["cs"], slot, tree,
-                                    steps=steps, rnd=rnd)
-        return out
+        with obs_trace.scope("gda.mix"):
+            if engine is None:
+                return exact.mix(gossip, tree, steps)
+            out, box["cs"] = engine.mix(box["cs"], slot, tree,
+                                        steps=steps, rnd=rnd)
+            return out
 
     return mix, lambda: box["cs"]

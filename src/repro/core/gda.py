@@ -37,6 +37,13 @@ Faithfulness notes
   tangent projection is linear and P_x(x) = 0.
 * The y-update adds an explicit projection onto Y (the paper states
   y in Y compact convex; its analysis needs feasible iterates).
+
+Device scopes (:func:`repro.obs.trace.scope`) name the step's phases in
+the compiled program: ``gda.grad`` (model forward and backward, tangent
+projection of the gradient), ``gda.retract`` (descent direction and
+retraction), ``gda.track`` (y ascent and projection, ``u``/``v``
+trackers), ``gda.metrics`` (``StepMetrics``) and, innermost wherever a
+mix runs, ``gda.mix``.
 """
 from __future__ import annotations
 
@@ -50,6 +57,7 @@ import jax.numpy as jnp
 from repro.comms import layer as comms_layer
 from repro.core.gossip import GossipSpec
 from repro.core.minimax import MinimaxProblem
+from repro.obs import trace as obs_trace
 from repro.obs import wire as obs_wire
 
 Array = jax.Array
@@ -162,20 +170,25 @@ class DecentralizedGDA:
                                     **({"method": h.invsqrt}
                                        if kind == "polar" else {}))
 
-        x_new = jax.tree.map(leaf_update, self.problem.manifold_map,
-                             state.x, mixed_x, state.u)
+        with obs_trace.scope("gda.retract"):
+            x_new = jax.tree.map(leaf_update, self.problem.manifold_map,
+                                 state.x, mixed_x, state.u)
 
         # ---- step 5: Euclidean consensus + tracked ascent on y ------------
-        y_new = jax.vmap(self.problem.project_y)(
-            mix("y", state.y, k) + h.eta * state.v)
+        with obs_trace.scope("gda.track"):
+            y_new = jax.vmap(self.problem.project_y)(
+                mix("y", state.y, k) + h.eta * state.v)
 
         # ---- steps 6/7: gradient tracking ----------------------------------
-        (loss_new, (rgx_new, gy_new)) = _vmapped_loss_and_rgrads(
-            self.problem, x_new, y_new, batch, self.backend.node_map)
+        with obs_trace.scope("gda.grad"):
+            (loss_new, (rgx_new, gy_new)) = _vmapped_loss_and_rgrads(
+                self.problem, x_new, y_new, batch, self.backend.node_map)
 
-        u_new = jax.tree.map(lambda mu, g, gp: mu + g - gp,
-                             mix("u", state.u, k), rgx_new, state.gx_prev)
-        v_new = mix("v", state.v, 1) + gy_new - state.gy_prev
+        with obs_trace.scope("gda.track"):
+            u_new = jax.tree.map(lambda mu, g, gp: mu + g - gp,
+                                 mix("u", state.u, k), rgx_new,
+                                 state.gx_prev)
+            v_new = mix("v", state.v, 1) + gy_new - state.gy_prev
 
         obs_new = obs_final()
         if self.telemetry is not None:
@@ -184,15 +197,16 @@ class DecentralizedGDA:
                              gx_prev=rgx_new, gy_prev=gy_new,
                              step=state.step + 1, comm=comm_final(),
                              obs=obs_new)
-        metrics = StepMetrics(
-            loss=jnp.mean(loss_new),
-            grad_norm_x=_tree_mean_norm(rgx_new),
-            grad_norm_y=jnp.mean(jnp.linalg.norm(
-                gy_new.reshape(gy_new.shape[0], -1), axis=-1)),
-            consensus_x=_tree_consensus(x_new),
-            consensus_y=_consensus(y_new),
-            tracker_norm_u=_tree_mean_norm(u_new),
-        )
+        with obs_trace.scope("gda.metrics"):
+            metrics = StepMetrics(
+                loss=jnp.mean(loss_new),
+                grad_norm_x=_tree_mean_norm(rgx_new),
+                grad_norm_y=jnp.mean(jnp.linalg.norm(
+                    gy_new.reshape(gy_new.shape[0], -1), axis=-1)),
+                consensus_x=_tree_consensus(x_new),
+                consensus_y=_consensus(y_new),
+                tracker_norm_u=_tree_mean_norm(u_new),
+            )
         return new_state, metrics
 
     def make_step(self, donate: bool = True) -> Callable:

@@ -13,6 +13,11 @@ Layer stacking: a :class:`Stage` repeats a supercell ``repeat`` times; its
 parameters (and caches) carry a leading ``repeat`` axis and the supercell
 body compiles once (flat compile time in depth — 62-layer Gemma compiles a
 6-block body).
+
+Device scopes (:func:`repro.obs.trace.scope`), the same in every mode:
+``model.layers`` around each stage, ``block.attention`` / ``block.mlp``
+inside an attention block, ``model.head`` around the final norm and the
+unembedding.
 """
 from __future__ import annotations
 
@@ -29,6 +34,7 @@ from repro.models import ssm as ssm_mod
 from repro.models import xlstm as xlstm_mod
 from repro.models.layers import (dense_init, embed_init, rmsnorm,
                                  rmsnorm_init, swiglu, swiglu_init)
+from repro.obs import trace as obs_trace
 
 Array = jax.Array
 PyTree = Any
@@ -168,40 +174,49 @@ def apply_block(params: dict, cfg: ModelConfig, spec: BlockSpec, x: Array,
                 positions: Array, mode: str, cache: Optional[dict],
                 frontend_embeds: Optional[Array],
                 cache_len: Optional[int] = None):
-    """Returns (x, aux_loss, new_cache)."""
+    """Returns (x, aux_loss, new_cache).  Attention blocks run in the
+    device scopes ``block.attention`` (norm, attention, residual) and
+    ``block.mlp`` (norm, MLP or MoE, residual)."""
     aux = jnp.zeros((), jnp.float32)
-    h = rmsnorm(params["ln1"], x, cfg.norm_eps)
 
     if spec.kind in ("attn", "moe_attn"):
         a = spec.attn
-        if mode == "decode":
-            if a.kind != "mla" and isinstance(cache, dict) \
-                    and "k_pages" in cache:
-                # paged serving path: ``positions`` is (position, block_table)
-                y, cache = attn_mod.gqa_decode_paged(
-                    params["attn"], h, cfg, a, positions, cache)
+        with obs_trace.scope("block.attention"):
+            h = rmsnorm(params["ln1"], x, cfg.norm_eps)
+            if mode == "decode":
+                if a.kind != "mla" and isinstance(cache, dict) \
+                        and "k_pages" in cache:
+                    # paged serving path: ``positions`` is (position,
+                    # block_table)
+                    y, cache = attn_mod.gqa_decode_paged(
+                        params["attn"], h, cfg, a, positions, cache)
+                else:
+                    fn = attn_mod.mla_decode if a.kind == "mla" \
+                        else attn_mod.gqa_decode
+                    y, cache = fn(params["attn"], h, cfg, a, positions,
+                                  cache)
             else:
-                fn = attn_mod.mla_decode if a.kind == "mla" \
-                    else attn_mod.gqa_decode
-                y, cache = fn(params["attn"], h, cfg, a, positions, cache)
-        else:
-            fn = attn_mod.mla_prefill if a.kind == "mla" else attn_mod.gqa_prefill
-            cl = attn_mod.attn_cache_len(a, cache_len or x.shape[1])
-            y, cache = fn(params["attn"], h, cfg, a, positions,
-                          make_cache=(mode == "prefill"), cache_len=cl)
-        x = x + y
-        if a.cross_attn and frontend_embeds is not None:
-            hx = rmsnorm(params["ln_x"], x, cfg.norm_eps)
-            fkv = attn_mod.make_frontend_kv(params["attn"], frontend_embeds, cfg)
-            x = x + attn_mod.cross_attend(params["attn"], hx, cfg, fkv)
-        h2 = rmsnorm(params["ln2"], x, cfg.norm_eps)
-        if spec.kind == "moe_attn":
-            y2, aux = moe_mod.apply_moe(params["moe"], h2, spec.moe)
-            x = x + y2
-        elif "mlp" in params:
-            x = x + swiglu(params["mlp"], h2)
+                fn = attn_mod.mla_prefill if a.kind == "mla" \
+                    else attn_mod.gqa_prefill
+                cl = attn_mod.attn_cache_len(a, cache_len or x.shape[1])
+                y, cache = fn(params["attn"], h, cfg, a, positions,
+                              make_cache=(mode == "prefill"), cache_len=cl)
+            x = x + y
+            if a.cross_attn and frontend_embeds is not None:
+                hx = rmsnorm(params["ln_x"], x, cfg.norm_eps)
+                fkv = attn_mod.make_frontend_kv(params["attn"],
+                                                frontend_embeds, cfg)
+                x = x + attn_mod.cross_attend(params["attn"], hx, cfg, fkv)
+        with obs_trace.scope("block.mlp"):
+            h2 = rmsnorm(params["ln2"], x, cfg.norm_eps)
+            if spec.kind == "moe_attn":
+                y2, aux = moe_mod.apply_moe(params["moe"], h2, spec.moe)
+                x = x + y2
+            elif "mlp" in params:
+                x = x + swiglu(params["mlp"], h2)
         return x, aux, cache
 
+    h = rmsnorm(params["ln1"], x, cfg.norm_eps)
     if spec.kind == "mamba":
         if mode == "decode":
             y, cache = ssm_mod.mamba_decode(params["mamba"], h, cfg, spec.ssm, cache)
@@ -350,15 +365,17 @@ def forward(params: dict, cfg: ModelConfig, tokens: Array, *,
     aux_total = jnp.zeros((), jnp.float32)
     caches = {}
     for i, st in enumerate(cfg.stages):
-        x, aux, nc = apply_stage(params["stages"][f"s{i}"], cfg, st, x,
-                                 positions, mode, None, fe, cache_len)
+        with obs_trace.scope("model.layers"):
+            x, aux, nc = apply_stage(params["stages"][f"s{i}"], cfg, st, x,
+                                     positions, mode, None, fe, cache_len)
         aux_total += aux
         if nc is not None:
             caches[f"s{i}"] = nc
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    if last_logits_only:
-        x = x[:, -1:]
-    logits = unembed(params, cfg, x)
+    with obs_trace.scope("model.head"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        if last_logits_only:
+            x = x[:, -1:]
+        logits = unembed(params, cfg, x)
     return logits, aux_total, (caches if mode == "prefill" else None)
 
 
@@ -373,9 +390,11 @@ def decode_step(params: dict, cfg: ModelConfig, token: Array, position: Array,
     fe = project_frontend(params, cfg, frontend_embeds)
     new_caches = {}
     for i, st in enumerate(cfg.stages):
-        x, _, nc = apply_stage(params["stages"][f"s{i}"], cfg, st, x,
-                               position, "decode", caches[f"s{i}"], fe)
+        with obs_trace.scope("model.layers"):
+            x, _, nc = apply_stage(params["stages"][f"s{i}"], cfg, st, x,
+                                   position, "decode", caches[f"s{i}"], fe)
         new_caches[f"s{i}"] = nc
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params, cfg, x)
-    return logits[:, 0], new_caches
+    with obs_trace.scope("model.head"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = unembed(params, cfg, x)[:, 0]
+    return logits, new_caches

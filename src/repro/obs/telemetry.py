@@ -3,7 +3,9 @@
 One ``Telemetry`` object per run ties the three layers together:
 
 * **spans / trace** — a host-side :class:`repro.obs.trace.Trace` whose
-  Chrome-trace JSON lands under ``out_dir`` at :meth:`export`;
+  Chrome-trace JSON lands under ``out_dir`` at :meth:`export`; each span
+  is also a program span on the profiler's clock
+  (:func:`repro.obs.trace.span`);
 * **jit counters** — :meth:`init_counters` seeds the packed ``f32[6]``
   counter leaf (``WireCounters`` is its host-side view) and
   :meth:`flush_counters` emits it from *inside* a
@@ -175,26 +177,3 @@ def read_counter_series(events_path: str) -> list[dict]:
             if ev["type"] == "counters"]
     rows.sort(key=lambda ev: ev.get("step", 0))
     return rows
-
-
-def latest_dashboard(events_path: str) -> Optional[dict]:
-    rows = [ev for ev in obs_events.read_jsonl(events_path)
-            if ev["type"] == "dashboard"]
-    return max(rows, key=lambda ev: ev.get("step", 0)) if rows else None
-
-
-def summarize_run(events_path: str) -> dict:
-    """Compact JSON summary of one event log (used by build_report)."""
-    counters = read_counter_series(events_path)
-    dash = latest_dashboard(events_path)
-    out: dict = {"n_events": obs_events.validate_log(events_path)}
-    if counters:
-        last = counters[-1]
-        out["counters"] = last["data"]
-        out["counters_step"] = last.get("step")
-        hops = max(last["data"].get("hops", 0), 1)
-        out["bytes_per_hop"] = last["data"].get("wire_bytes", 0.0) / hops
-    if dash:
-        out["dashboard"] = dash["data"]
-        out["dashboard_step"] = dash.get("step")
-    return out

@@ -1,18 +1,29 @@
-"""Host-side spans + Chrome-trace / Perfetto export.
+"""Program spans and scopes on the profiler's clock, plus Chrome-trace /
+Perfetto export.
 
-``Trace`` records wall-clock *complete* events ("ph": "X") from the
+Two mechanisms mark where time goes, both read from a JAX profiler trace
+(``jax.profiler.start_trace`` / ``stop_trace``):
+
+* :func:`scope` names a region *inside* a jitted program.  It is
+  ``jax.named_scope``: the name lands in the ``op_name`` metadata of every
+  HLO instruction lowered inside it (``jit(step)/gda.retract/...``) and
+  adds no operation, so the device program is the same with or without
+  it.  A device op belongs to the innermost scope of a family
+  (``gda.``, ``model.``/``block.``) in its ``op_name``.
+* :func:`span` marks a *host* region as a ``jax.profiler.TraceAnnotation``
+  on the profiler's clock, the clock the device ops are on.  Outside a
+  profiler trace it costs one annotation object.
+
+``Trace`` records wall-clock *complete* events ("ph": "X") from its
 ``span()`` context manager (nesting is reconstructed by Perfetto from the
-timestamps), counter tracks ("ph": "C") from flushed jit counters, and
-instants.  ``to_chrome_trace()`` emits the standard
-``{"traceEvents": [...]}`` JSON that both ``chrome://tracing`` and
+timestamps) and counter tracks ("ph": "C") from flushed jit counters; each
+of its spans is also a program span.  ``to_chrome_trace()`` emits the
+standard ``{"traceEvents": [...]}`` JSON that both ``chrome://tracing`` and
 https://ui.perfetto.dev open directly; ``from_chrome_trace`` round-trips it
 (schema-checked by ``tests/test_obs.py``).
 
 Timestamps are microseconds since the trace epoch (``t0``), per the trace
-event format.  Spans are cheap (one ``perf_counter`` pair + a dict append)
-— they wrap *host* boundaries (a jitted step call, an eval pass, a
-benchmark phase), never code inside a jit trace; in-jit accounting is
-``obs.wire``'s job.
+event format.
 """
 from __future__ import annotations
 
@@ -22,6 +33,18 @@ import os
 import threading
 import time
 from typing import Any, Optional
+
+import jax
+
+
+def scope(name: str):
+    """Device scope: ``jax.named_scope(name)`` around traced code."""
+    return jax.named_scope(name)
+
+
+def span(name: str):
+    """Host span on the profiler's clock around host code."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 class Trace:
@@ -50,20 +73,17 @@ class Trace:
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "host", **args: Any):
-        """Wall-clock region: ``with trace.span("step", step=t): ...``."""
+        """Wall-clock region: ``with trace.span("step", step=t): ...``;
+        also a program span of the same name."""
         t0 = self._now_us()
         try:
-            yield self
+            with span(name):
+                yield self
         finally:
             t1 = self._now_us()
             self._append({"name": name, "cat": cat, "ph": "X", "ts": t0,
                           "dur": t1 - t0, "pid": self.pid, "tid": self._tid(),
                           "args": args})
-
-    def instant(self, name: str, cat: str = "host", **args: Any) -> None:
-        self._append({"name": name, "cat": cat, "ph": "i", "s": "g",
-                      "ts": self._now_us(), "pid": self.pid,
-                      "tid": self._tid(), "args": args})
 
     def counter(self, name: str, values: dict[str, float],
                 ts: Optional[float] = None) -> None:

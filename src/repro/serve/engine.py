@@ -15,10 +15,19 @@ only the first ``len`` cache rows are scattered into pages).
 
 :func:`serve_requests` is the reference serving loop wiring this engine to
 a :class:`~repro.serve.scheduler.ContinuousBatchingScheduler`.
+
+Program spans (:func:`repro.obs.trace.span`, on the profiler's clock) mark
+the host's part: ``engine.prefill`` (prefill program and first token),
+``engine.scatter`` (the prompt's K/V into its pages), per decode wave
+``engine.wave.inputs`` (key split, uploads of tokens, positions and block
+table), ``engine.wave.launch`` and ``engine.wave.fetch`` (the sampled
+tokens back on the host); in the loop ``loop.sched`` (admission),
+``loop.tokens`` (hand-off of tokens, releases) and ``loop.wait`` (sleep
+until the next arrival).  The wave's sampling runs in the device scope
+``model.head``.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import time
 from typing import Optional
@@ -29,6 +38,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import transformer
+from repro.obs import trace as obs_trace
 from repro.serve import kv_cache
 from repro.serve.kv_cache import PagedKVSpec
 from repro.serve.scheduler import ContinuousBatchingScheduler, Request
@@ -78,7 +88,9 @@ class ServeEngine:
     def _step_impl(self, params, tok, positions, bt, pools, key):
         logits, new_pools = transformer.decode_step(
             params, self.cfg, tok, (positions, bt), pools)
-        return _sample(logits, key, self.temperature), new_pools
+        with obs_trace.scope("model.head"):
+            nxt = _sample(logits, key, self.temperature)
+        return nxt, new_pools
 
     def _prefill_fn(self, cache_len: int):
         fn = self._prefill_fns.get(cache_len)
@@ -90,11 +102,6 @@ class ServeEngine:
                 return logits[0, last], caches
             fn = self._prefill_fns[cache_len] = jax.jit(body)
         return fn
-
-    def _span(self, name: str):
-        if self.telemetry is None:
-            return contextlib.nullcontext()
-        return self.telemetry.span(name)
 
     # -- slot lifecycle -----------------------------------------------------
 
@@ -111,13 +118,14 @@ class ServeEngine:
 
         tokens = np.zeros((1, cache_len), np.int32)
         tokens[0, :length] = prompt
-        with self._span("serve.prefill"):
+        with obs_trace.span("engine.prefill"):
             last_logits, caches = self._prefill_fn(cache_len)(
                 self.params, jnp.asarray(tokens),
                 jnp.asarray(length - 1, jnp.int32))
             self._key, k = jax.random.split(self._key)
             first = int(_sample(last_logits[None], k,
                                 self.temperature)[0])
+        with obs_trace.span("engine.scatter"):
             self.pools = self._scatter(
                 self.pools, caches, jnp.asarray(pages[:npg], jnp.int32))
 
@@ -145,12 +153,15 @@ class ServeEngine:
         """One fused decode step for every slot; returns the (n_slots,)
         sampled tokens (garbage at inactive slots — callers consult the
         scheduler for liveness)."""
-        self._key, k = jax.random.split(self._key)
-        with self._span("serve.step"):
-            nxt, self.pools = self._step(
-                self.params, jnp.asarray(self._tokens),
-                jnp.asarray(self._positions), jnp.asarray(self._bt),
-                self.pools, k)
+        with obs_trace.span("engine.wave.inputs"):
+            self._key, k = jax.random.split(self._key)
+            tok = jnp.asarray(self._tokens)
+            pos = jnp.asarray(self._positions)
+            bt = jnp.asarray(self._bt)
+        with obs_trace.span("engine.wave.launch"):
+            nxt, self.pools = self._step(self.params, tok, pos, bt,
+                                         self.pools, k)
+        with obs_trace.span("engine.wave.fetch"):
             nxt = np.asarray(nxt)
         act = self._active
         self._tokens[act] = nxt[act]
@@ -175,16 +186,21 @@ def serve_requests(engine: ServeEngine,
         sched.submit(r)
 
     while not sched.idle:
-        for slot, req in sched.admit(now()):
+        with obs_trace.span("loop.sched"):
+            admitted = sched.admit(now())
+        for slot, req in admitted:
             first = engine.admit(slot, req.prompt, sched.slots[slot].pages)
-            if sched.on_token(slot, first, now()) is not None:
-                engine.release(slot)
+            with obs_trace.span("loop.tokens"):
+                if sched.on_token(slot, first, now()) is not None:
+                    engine.release(slot)
         if sched.n_active == 0:
-            time.sleep(idle_sleep)      # waiting on future arrivals
+            with obs_trace.span("loop.wait"):
+                time.sleep(idle_sleep)      # waiting on future arrivals
             continue
         toks = engine.step()
-        t = now()
-        for slot in sched.active_slots():
-            if sched.on_token(slot, int(toks[slot]), t) is not None:
-                engine.release(slot)
+        with obs_trace.span("loop.tokens"):
+            t = now()
+            for slot in sched.active_slots():
+                if sched.on_token(slot, int(toks[slot]), t) is not None:
+                    engine.release(slot)
     return sched.finished

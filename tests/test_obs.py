@@ -256,12 +256,11 @@ def test_trace_perfetto_roundtrip(tmp_path):
     with tr.span("outer", step=1):
         with tr.span("inner"):
             pass
-    tr.instant("marker")
     tr.counter("wire", {"wire_bytes": 123.0})
     payload = tr.to_chrome_trace()
     assert payload["otherData"]["run"] == "rt"
     phases = sorted(e["ph"] for e in payload["traceEvents"])
-    assert phases == ["C", "X", "X", "i"]
+    assert phases == ["C", "X", "X"]
     spans = {e["name"]: e for e in payload["traceEvents"] if e["ph"] == "X"}
     assert spans["inner"]["dur"] <= spans["outer"]["dur"]
     path = tr.save(str(tmp_path / "t.trace.json"))
