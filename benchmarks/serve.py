@@ -82,8 +82,8 @@ def _kernel_check():
     s, hkv, g, hd, ps, m = 5, 2, 3, 32, 8, 6
     n_pages = 24
     q = jnp.asarray(rng.normal(size=(s, hkv * g, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(n_pages, hkv, hd, ps)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(n_pages, hkv, hd, ps)), jnp.float32)
     seq = [1, 7, 13, 0, 40]
     bt = np.full((s, m), -1, np.int32)
     nxt = 1

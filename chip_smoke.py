@@ -65,7 +65,7 @@ RING_RTOL = {"fp32": 1e-3, "int8": 1e-2}
 
 ARCH = "smollm-135m"
 NODES, BATCH, SEQ, STEPS = 4, 2, 512, 3          # train: 4 x (2 x 512)
-SLOTS, PROMPT, NEW, PAGE = 4, 128, 32, 16        # serve
+SLOTS, PROMPT, NEW, PAGE = 4, 128, 32, 128       # serve
 TRAIN_ARGS = ["--arch", ARCH, "--optimizer", "drsgda", "--nodes", str(NODES),
               "--batch-per-node", str(BATCH), "--seq-len", str(SEQ),
               "--steps", str(STEPS), "--eval-every", str(STEPS)]
@@ -159,8 +159,8 @@ def kernels_phase(cfg) -> None:
     slots, ps, m = SLOTS, PAGE, -(-(PROMPT + NEW) // PAGE)
     n_pages = slots * m * 2 + 1
     qd = _bf16_exact(ks[4], (slots, h, hd))
-    kp = _bf16_exact(ks[5], (n_pages, ps, hkv, hd))
-    vp = _bf16_exact(ks[6], (n_pages, ps, hkv, hd))
+    kp = _bf16_exact(ks[5], (n_pages, hkv, hd, ps))
+    vp = _bf16_exact(ks[6], (n_pages, hkv, hd, ps))
     seq = np.asarray([m * ps, PROMPT + 1, PROMPT // 2 + 13, 1], np.int32)
     bt = np.full((slots, m), -1, np.int32)
     nxt = 1

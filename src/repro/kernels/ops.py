@@ -185,7 +185,7 @@ def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
                            pages_per_block: int | None = None) -> Array:
     """One decode step for S slots over a paged KV pool.
 
-    q (S, H, hd); pools (P, page_size, Hkv, hd/hdv); block_table (S, M)
+    q (S, H, hd); pools (P, Hkv, hd/hdv, page_size); block_table (S, M)
     int32 (-1 = unallocated); seq_lens (S,) int32 (valid tokens, the query
     sits at ``seq_lens - 1``).  Returns (S, H, hdv).
 
@@ -196,7 +196,7 @@ def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
     """
     impl = impl or _default_impl()
     s_slots, h, hd = q.shape
-    ps, hkv = k_pages.shape[1], k_pages.shape[2]
+    hkv, ps = k_pages.shape[1], k_pages.shape[3]
     m_pages = block_table.shape[1]
     _est.record("paged_decode", _est.paged_decode_est(
         s_slots, h, hkv, hd, m_pages, ps, itemsize=_itemsize(q)))
@@ -217,7 +217,29 @@ def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
         qg, k_pages, v_pages, bt, seq_lens, window=window,
         softmax_scale=softmax_scale, pages_per_block=pages_per_block,
         interpret=(impl == "pallas_interpret"))
-    return out.reshape(s_slots, h, v_pages.shape[-1])
+    return out.reshape(s_slots, h, v_pages.shape[2])
+
+
+def paged_write(pool: Array, block_table: Array, position: Array,
+                rows: Array) -> Array:
+    """Write one token per slot into a paged pool.
+
+    pool (P, Hkv, hd, page_size); block_table (S, M) int32; position (S,)
+    int32; rows (S, Hkv, hd) land at ``position`` of each slot's pages
+    (inactive slots, whose block-table rows are all -1, on the dump page 0).
+
+    Written as whole pages, gathered, merged at the token's lane and
+    scattered back: a scatter of one token's (Hkv, hd) would want those dims
+    minor in the pool's layout, and the chip's compiler would then copy the
+    whole pool into that layout around every write.  Slots sharing a page
+    (inactive ones, all on the dump page) write it in any order.
+    """
+    ps = pool.shape[-1]
+    page = jnp.maximum(
+        block_table[jnp.arange(rows.shape[0]), position // ps], 0)
+    lane = jnp.arange(ps) == (position % ps)[:, None]          # (S, ps)
+    pages = jnp.where(lane[:, None, None, :], rows[..., None], pool[page])
+    return pool.at[page].set(pages)
 
 
 # ---------------------------------------------------------------------------

@@ -2,7 +2,7 @@
 
 The serving path's KV cache is *paged* (``repro.serve.kv_cache``): each
 decode slot owns a row of a block table whose entries index fixed-size
-pages ``(page_size, Hkv, hd)`` inside one shared pool.  This kernel runs
+pages ``(Hkv, hd, page_size)`` inside one shared pool.  This kernel runs
 one decode step for every slot — q is a single token per slot — attending
 over that slot's pages with an online softmax, **gathering pages through
 the block table inside the kernel**: the table and the per-slot sequence
@@ -11,7 +11,11 @@ index_map can pick the next physical page while the previous block is
 still being computed.
 
 Layout: q ``(S, Hkv, G, hd)`` (S slots, G = n_heads // n_kv_heads query
-heads per kv head); pools ``(P, page_size, Hkv, hd)``; block table
+heads per kv head); pools ``(P, Hkv, hd, page_size)``, token-minor (on the
+chip a page size that is a multiple of 128 fills whole lanes, so the pool
+holds no padding and XLA keeps it in the row-major layout this kernel
+reads, with no copy around the call; smaller pages are relaid out around
+it); block table
 ``(S, M)`` int32 (-1 = unallocated; reads clamp to page 0, the dump page,
 and are fully masked); seq_lens ``(S,)`` int32 — valid tokens including
 the current query token at position ``seq_lens - 1``.
@@ -20,7 +24,8 @@ Grid: ``(S, M // pages_per_block)`` with the page loop innermost — TPU
 grid execution is sequential there, so the (acc, m, l) VMEM scratch
 persists across page steps exactly like ``flash_attention``'s kv loop.
 Each step fetches whole pages (all Hkv heads) and loops over the kv heads
-in the kernel body.
+in the kernel body: scores are ``q (G, hd) @ k (hd, ps)``, values enter as
+``p (G, ps) · v (hdv, ps)ᵀ``.
 ``pages_per_block`` fuses several page fetches per grid step (the tuned
 knob, see ``kernels/tune.py``) by passing the pool once per fused page
 with staggered index_maps.
@@ -61,34 +66,33 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, *refs, scale: float,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     sl = sl_ref[i]                                       # valid tokens
-    span = g_pages * page_size
-    pos = j * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
-    mask = pos < sl                                      # (1, span)
-    if window is not None:
-        # the query sits at position sl - 1
-        mask &= (sl - 1 - pos) < window
+    for r in range(g_pages):     # static: each fetched page in turn
+        pos = (j * g_pages + r) * page_size + \
+            jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
+        mask = pos < sl                                  # (1, ps)
+        if window is not None:
+            # the query sits at position sl - 1
+            mask &= (sl - 1 - pos) < window
 
-    for kh in range(hkv):        # static: every kv head of the fetched pages
-        q = q_ref[0, kh].astype(jnp.float32) * scale     # (G, hd)
-        k = jnp.concatenate([r[0, :, kh, :] for r in k_refs], axis=0) \
-            .astype(jnp.float32)                         # (span, hd)
-        v = jnp.concatenate([r[0, :, kh, :] for r in v_refs], axis=0) \
-            .astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(mask, s, NEG_INF)                  # (G, span)
+        for kh in range(hkv):    # static: every kv head of the fetched page
+            q = q_ref[0, kh].astype(jnp.float32) * scale     # (G, hd)
+            k = k_refs[r][0, kh].astype(jnp.float32)         # (hd, ps)
+            v = v_refs[r][0, kh].astype(jnp.float32)         # (hdv, ps)
+            s = jax.lax.dot_general(q, k, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s, NEG_INF)                  # (G, ps)
 
-        m_prev = m_ref[kh]                               # (G, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        # fully-masked spans (empty slots / dump pages): keep rows exactly 0
-        p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[kh] = l_ref[kh] * corr + p.sum(axis=-1, keepdims=True)
-        acc_ref[kh] = acc_ref[kh] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[kh] = m_new
+            m_prev = m_ref[kh]                               # (G, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            # fully-masked pages (empty slots / dump pages): rows stay 0
+            p = jnp.where(mask, p, 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[kh] = l_ref[kh] * corr + p.sum(axis=-1, keepdims=True)
+            acc_ref[kh] = acc_ref[kh] * corr + jax.lax.dot_general(
+                p, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[kh] = m_new
 
     @pl.when(j == n_blocks - 1)
     def _finalize():
@@ -106,15 +110,16 @@ def paged_decode_shgd(q: Array, k_pages: Array, v_pages: Array,
                       softmax_scale: float | None = None,
                       pages_per_block: int = DEFAULT_PAGES_PER_BLOCK,
                       interpret: bool = False) -> Array:
-    """q: (S, Hkv, G, hd); pools (P, ps, Hkv, hd/hdv); block_table (S, M)
+    """q: (S, Hkv, G, hd); pools (P, Hkv, hd/hdv, ps); block_table (S, M)
     int32; seq_lens (S,) int32.  Returns (S, Hkv, G, hdv).
 
     ``M % pages_per_block == 0`` (ops.py pads the table with -1 columns);
-    the page size should divide by 8 (16 for bf16 pools) on the chip.
+    on the chip the page size should be a multiple of 128 (or the pool's
+    whole last dim) and hd divide by 8 (16 for bf16 pools).
     """
     s_slots, hkv, group, hd = q.shape
-    n_pages, ps, _, _ = k_pages.shape
-    hdv = v_pages.shape[-1]
+    n_pages, _, _, ps = k_pages.shape
+    hdv = v_pages.shape[2]
     m_pages = block_table.shape[1]
     g = pages_per_block
     assert m_pages % g == 0, (m_pages, g)
@@ -130,14 +135,14 @@ def paged_decode_shgd(q: Array, k_pages: Array, v_pages: Array,
             return (jnp.maximum(bt[i, j * g + off], 0), 0, 0, 0)
         return index
 
-    # every block spans Hkv and hd whole: the chip's tiling rule (last two
-    # block dims divisible by (8, 128) or equal to the array's) then holds
-    # for any head count and head dim
+    # every block spans Hkv, hd and the page whole: the chip's tiling rule
+    # (last two block dims divisible by (8, 128) or equal to the array's)
+    # then holds for any head count, head dim and page size
     in_specs = [pl.BlockSpec((1, hkv, group, hd),
                              lambda i, j, bt, sl: (i, 0, 0, 0))]
-    in_specs += [pl.BlockSpec((1, ps, hkv, hd), page_map(off))
+    in_specs += [pl.BlockSpec((1, hkv, hd, ps), page_map(off))
                  for off in range(g)]
-    in_specs += [pl.BlockSpec((1, ps, hkv, hdv), page_map(off))
+    in_specs += [pl.BlockSpec((1, hkv, hdv, ps), page_map(off))
                  for off in range(g)]
 
     kernel = functools.partial(_paged_kernel, scale=scale, window=window,

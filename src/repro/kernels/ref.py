@@ -129,18 +129,21 @@ def paged_decode_attention_ref(q: Array, k_pages: Array, v_pages: Array,
     the block table into a contiguous (S, M*ps, Hkv, hd) view, then run the
     streaming attention oracle with positions derived from the page layout.
 
-    q (S, H, hd) — one query token per slot; pools (P, ps, Hkv, hd/hdv);
+    q (S, H, hd) — one query token per slot; pools (P, Hkv, hd/hdv, ps);
     block_table (S, M) int32 (-1 = unallocated, clamped to page 0 and fully
     masked); seq_lens (S,) int32 — valid tokens, query at ``seq_lens - 1``.
     A slot with ``seq_lens == 0`` returns exact zeros (all keys masked).
     """
     s_slots = q.shape[0]
-    ps, hkv, hd = k_pages.shape[1:]
-    hdv = v_pages.shape[-1]
+    hkv, hd, ps = k_pages.shape[1:]
+    hdv = v_pages.shape[2]
     m_pages = block_table.shape[1]
     bt = jnp.maximum(block_table, 0)
-    k = k_pages[bt].reshape(s_slots, m_pages * ps, hkv, hd)
-    v = v_pages[bt].reshape(s_slots, m_pages * ps, hkv, hdv)
+    # (S, M, Hkv, hd, ps) token-minor pages -> (S, M * ps, Hkv, hd)
+    k = jnp.moveaxis(k_pages[bt], -1, 2).reshape(s_slots, m_pages * ps,
+                                                 hkv, hd)
+    v = jnp.moveaxis(v_pages[bt], -1, 2).reshape(s_slots, m_pages * ps,
+                                                 hkv, hdv)
     pos = jnp.arange(m_pages * ps, dtype=jnp.int32)[None, :]
     kv_pos = jnp.where(pos < seq_lens[:, None], pos, -1)
     q_pos = seq_lens[:, None].astype(jnp.int32) - 1

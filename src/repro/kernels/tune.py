@@ -233,9 +233,9 @@ def _probe_inputs(kernel: str, shape: tuple, dtype: Any, extra: dict):
         h, hkv = PAGED_PROBE_HEADS
         n_pages = s * m + 1                      # + the dump page
         q = jax.random.normal(ks[0], (s, h, hd), jnp.float32).astype(dtype)
-        kp = jax.random.normal(ks[1], (n_pages, ps, hkv, hd),
+        kp = jax.random.normal(ks[1], (n_pages, hkv, hd, ps),
                                jnp.float32).astype(dtype)
-        vp = jax.random.normal(ks[2], (n_pages, ps, hkv, hd),
+        vp = jax.random.normal(ks[2], (n_pages, hkv, hd, ps),
                                jnp.float32).astype(dtype)
         # ragged slots: slot i holds ~ (i+1)/s of the max context
         seq = jnp.asarray([max(1, ((i + 1) * m * ps) // s)

@@ -122,7 +122,8 @@ def main(argv=None) -> int:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.8)
-    ap.add_argument("--page-size", type=int, default=16)
+    ap.add_argument("--page-size", type=int, default=128,
+                    help="tokens per KV page; a multiple of 128 on the chip")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--legacy", action="store_true",
                     help="force the contiguous-cache decode path")

@@ -91,10 +91,11 @@ def gqa_decode_paged(params, x: Array, cfg: ModelConfig, spec: AttnSpec,
     ``pos_bt`` is ``(position, block_table)``: per-slot positions (S,) int32
     of the *incoming* token, and the shared block table (S, M) int32 — they
     ride together through ``decode_step``'s opaque ``position`` argument.
-    ``cache`` holds this layer's ``{"k_pages", "v_pages"}`` pools; the new
-    token's K/V are scattered into the slot's current page (inactive slots
-    land on the dump page 0), then attention runs through the block-table
-    gather kernel with ``seq_lens = position + 1``."""
+    ``cache`` holds this layer's ``{"k_pages", "v_pages"}`` pools (their
+    layout is the kernel layer's, ``ops.paged_write``); the new token's K/V
+    are written into the slot's current page (inactive slots land on the
+    dump page 0), then attention runs through the block-table gather kernel
+    with ``seq_lens = position + 1``."""
     position, block_table = pos_bt
     s, _, _ = x.shape
     hd, h, hkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
@@ -105,11 +106,8 @@ def gqa_decode_paged(params, x: Array, cfg: ModelConfig, spec: AttnSpec,
     q = apply_rope(q, pos2, cfg.rope_theta)
     k = apply_rope(k, pos2, cfg.rope_theta)
 
-    ps = cache["k_pages"].shape[1]
-    page = jnp.maximum(block_table[jnp.arange(s), position // ps], 0)
-    off = position % ps
-    kp = cache["k_pages"].at[page, off].set(k[:, 0])
-    vp = cache["v_pages"].at[page, off].set(v[:, 0])
+    kp = ops.paged_write(cache["k_pages"], block_table, position, k[:, 0])
+    vp = ops.paged_write(cache["v_pages"], block_table, position, v[:, 0])
     y = ops.paged_decode_attention(q[:, 0], kp, vp, block_table,
                                    position + 1, window=spec.sliding_window)
     return (y.reshape(s, 1, h * hd) @ params["wo"],

@@ -264,23 +264,68 @@ def _apply_supercell(cell_params: dict, cfg: ModelConfig, stage: Stage,
     return x, aux_total, new_caches
 
 
+def _holds_pools(stage_cache: Optional[dict]) -> bool:
+    """Whether every block cache of a stage is a paged pool
+    (``repro.serve.kv_cache``) rather than a per-sequence cache."""
+    return bool(stage_cache) and all(
+        isinstance(c, dict) and "k_pages" in c for c in stage_cache.values())
+
+
+def _scan_paged_decode(cell_at, stage_params: dict, x: Array, positions,
+                       pools: dict, repeat: int):
+    """Decode a scanned stage with its paged pools carried whole.
+
+    ``cell_at(positions)`` gives the stage's cell for the positions (here
+    each layer's ``(position, block_table)``).
+
+    Each block's stacked pool ``(R, P, ...)`` is flattened to
+    ``(R * P, ...)`` (a bitcast) and rides the scan's carry; layer
+    ``i`` addresses its own pages through the block table offset by
+    ``i * P`` (``-1`` stays ``-1``: unallocated entries still clamp to one
+    page and are masked).  The new token's K/V are written in place into the
+    carried buffer and the paged kernel reads layer ``i``'s pages straight
+    from it, so no layer's pool is sliced out of the stack or stacked back.
+    Inactive slots write into page 0 of the flattened pool, layer 0's dump
+    page, from every layer."""
+    position, block_table = positions
+    n_pages = jax.tree.leaves(pools)[0].shape[1]
+    flat = jax.tree.map(lambda l: l.reshape(-1, *l.shape[2:]), pools)
+
+    def body(carry, scanned):
+        xx, aux_acc, pp = carry
+        p, i = scanned
+        bt = jnp.where(block_table >= 0, block_table + i * n_pages, -1)
+        xx, aux, pp = cell_at((position, bt))(p, xx, pp)
+        return (xx, aux_acc + aux, pp), None
+
+    (x, aux, flat), _ = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), flat),
+        (stage_params, jnp.arange(repeat, dtype=jnp.int32)))
+    return x, aux, jax.tree.map(lambda f, l: f.reshape(l.shape), flat, pools)
+
+
 def apply_stage(stage_params: dict, cfg: ModelConfig, stage: Stage, x: Array,
                 positions: Array, mode: str, stage_cache: Optional[dict],
                 frontend_embeds: Optional[Array],
                 cache_len: Optional[int] = None):
     want_cache = mode in ("prefill", "decode")
 
-    def cell(p, xx, cc):
+    def cell_at(pos):
         base = functools.partial(_apply_supercell, cfg=cfg, stage=stage,
-                                 positions=positions, mode=mode,
+                                 positions=pos, mode=mode,
                                  frontend_embeds=frontend_embeds,
                                  cache_len=cache_len)
-        if cfg.remat and mode == "train":
-            ck = jax.checkpoint(
-                lambda pp, xxx: base(pp, x=xxx, cell_cache=None),
-                policy=jax.checkpoint_policies.nothing_saveable)
-            return ck(p, xx)
-        return base(p, x=xx, cell_cache=cc)
+
+        def cell(p, xx, cc):
+            if cfg.remat and mode == "train":
+                ck = jax.checkpoint(
+                    lambda pp, xxx: base(pp, x=xxx, cell_cache=None),
+                    policy=jax.checkpoint_policies.nothing_saveable)
+                return ck(p, xx)
+            return base(p, x=xx, cell_cache=cc)
+        return cell
+
+    cell = cell_at(positions)
 
     if stage.repeat == 1:
         x, aux, nc = cell(stage_params, x, stage_cache)
@@ -302,6 +347,10 @@ def apply_stage(stage_params: dict, cfg: ModelConfig, stage: Stage, x: Array,
             stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *new_caches)
             return x, aux_total, stacked
         return x, aux_total, None
+
+    if mode == "decode" and _holds_pools(stage_cache):
+        return _scan_paged_decode(cell_at, stage_params, x, positions,
+                                  stage_cache, stage.repeat)
 
     def body(carry, scanned):
         xx, aux_acc = carry

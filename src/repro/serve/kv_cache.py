@@ -2,19 +2,30 @@
 
 Contiguous per-request KV buffers waste memory on ragged workloads — a
 4k-context slot and a 30-token slot cost the same.  Here every attention
-layer owns one *pool* of ``(n_pages, page_size, Hkv, hd)`` pages; a decode
-slot references its pages through a row of the shared block table
-``(n_slots, max_pages_per_slot)`` int32.  Unallocated entries are ``-1``;
-page 0 is the *dump page* — a write/read sink for inactive slots, never
-handed out by the allocator — so the fused decode step needs no host-side
-branching on slot liveness (``kernels/paged_decode.py`` clamps ``-1`` to 0
-and fully masks those positions).
+layer owns one *pool* of ``(n_pages, Hkv, hd, page_size)`` pages,
+token-minor; a decode slot references its pages through a row of the shared
+block table ``(n_slots, max_pages_per_slot)`` int32.  Unallocated entries
+are ``-1``; page 0 is the *dump page* — a write/read sink for inactive
+slots, never handed out by the allocator — so the fused decode step needs no
+host-side branching on slot liveness (``kernels/paged_decode.py`` clamps
+``-1`` to 0 and fully masks those positions).
 
 The pool pytree mirrors ``models.transformer.init_cache``'s stage/block
 structure (a leading ``repeat`` axis for scanned stages) with only
-``{"k_pages", "v_pages"}`` leaves, so it threads through ``apply_stage``'s
-scan machinery unchanged; :class:`PagePool` is the host-side allocator
-(free list + admission reservations) the scheduler draws from.
+``{"k_pages", "v_pages"}`` leaves.  In the decode wave a scanned stage's
+pools ride the layer scan's carry whole, flattened to
+``(repeat * n_pages, ...)``, and layer ``i`` addresses its pages through the
+block table offset by ``i * n_pages`` (``models.transformer``), so a wave
+rewrites only each slot's current page and copies no pool.
+:class:`PagePool` is the host-side allocator (free list + admission
+reservations) the scheduler draws from.
+
+On the chip the page size should be a multiple of 128.  A page's tokens
+then fill whole lanes: a head dim under 128 costs no padding, and the paged
+kernel and the decode write use the pool in the layout it is stored in.
+Under 128 the chip's compiler relays the pool out around every wave, whole
+pool copies in and out, and a pool sized for 128-token pages may no longer
+fit (PERF.md, open questions).
 """
 from __future__ import annotations
 
@@ -31,7 +42,7 @@ Array = jax.Array
 @dataclasses.dataclass(frozen=True)
 class PagedKVSpec:
     """Static geometry of the paged cache."""
-    page_size: int = 16          # tokens per page
+    page_size: int = 128         # tokens per page (see the module doc)
     n_pages: int = 64            # pool size per attention layer (incl. dump)
     max_pages_per_slot: int = 8  # block-table width M
 
@@ -116,7 +127,7 @@ def init_pools(cfg: ModelConfig, spec: PagedKVSpec,
     for i, st in enumerate(cfg.stages):
         cell = {}
         for j, sp in enumerate(st.blocks):
-            shape = (spec.n_pages, spec.page_size, cfg.n_kv_heads, cfg.hd)
+            shape = (spec.n_pages, cfg.n_kv_heads, cfg.hd, spec.page_size)
             cell[f"b{j}"] = {
                 "k_pages": jnp.zeros(shape, dtype),
                 "v_pages": jnp.zeros(shape, dtype),
@@ -142,9 +153,9 @@ def scatter_prompt(pools: dict, caches: dict, pages: Array, *,
     span = npg * page_size
 
     def put(pool: Array, rows: Array) -> Array:
-        # rows (cl, Hkv, hd) -> (np, ps, Hkv, hd) page-major
+        # rows (cl, Hkv, hd) -> (np, Hkv, hd, ps) page-major, token-minor
         seq = rows[:span].reshape(npg, page_size, *rows.shape[1:])
-        return pool.at[pages].set(seq)
+        return pool.at[pages].set(jnp.moveaxis(seq, 1, -1))
 
     out = {}
     for i, st in enumerate(cfg.stages):

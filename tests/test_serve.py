@@ -1,17 +1,24 @@
-"""Serving subsystem tests: paged kernel, engine equivalence, scheduler,
-replica gossip sync, tune registration, PRNG hygiene."""
+"""Serving subsystem tests: paged kernel, engine equivalence, the decode
+wave's carried pools, scheduler, replica gossip sync, tune registration,
+PRNG hygiene."""
+import dataclasses
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import configs
+from repro.configs.base import Stage
 from repro.kernels import ops, ref
 from repro.kernels import paged_decode as pd
 from repro.launch.serve import generate
 from repro.models import transformer as T
 from repro.serve import (ContinuousBatchingScheduler, PagedKVSpec,
-                         ReplicaGroup, Request, ServeEngine, serve_requests)
+                         ReplicaGroup, Request, ServeEngine, kv_cache,
+                         serve_requests)
 
 
 @pytest.fixture(scope="module")
@@ -25,8 +32,8 @@ def _paged_case(seed=0, s=5, hkv=2, g=1, hd=32, ps=8, m=6):
     rng = np.random.default_rng(seed)
     n_pages = s * m + 1
     q = jnp.asarray(rng.normal(size=(s, hkv * g, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(n_pages, hkv, hd, ps)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(n_pages, hkv, hd, ps)), jnp.float32)
     seq = [1, 7, 13, 0, min(m * ps, 40)][:s]
     bt = np.full((s, m), -1, np.int32)
     nxt = 1
@@ -48,7 +55,7 @@ def test_paged_kernel_matches_oracle(g, window):
     q, kp, vp, bt, seq = _paged_case(g=g)
     want = ref.paged_decode_attention_ref(q, kp, vp, bt, seq, window=window)
     s, h, hd = q.shape
-    hkv = kp.shape[2]
+    hkv = kp.shape[1]
     got = pd.paged_decode_shgd(
         q.reshape(s, hkv, h // hkv, hd), kp, vp, bt, seq, window=window,
         interpret=True).reshape(s, h, hd)
@@ -140,6 +147,127 @@ def test_engine_ragged_batch(smoke_model):
     for p in prompts:
         alone = run([p], 1)
         assert together[tuple(p)] == alone[tuple(p)]
+
+
+# ---------------------------------------------------------------------------
+# the decode wave: pools carried whole through the layer scan
+# ---------------------------------------------------------------------------
+
+
+def _two_block_variant(cfg):
+    """The smoke config with two GQA blocks per scanned supercell."""
+    blk = cfg.stages[0].blocks[0]
+    return dataclasses.replace(
+        cfg, stages=(Stage(blocks=(blk, blk), repeat=2),))
+
+
+@pytest.mark.parametrize("impl", ["ref", "pallas_interpret"])
+@pytest.mark.parametrize("cell", ["one_block", "two_blocks"])
+def test_carried_pools_write_only_live_rows(smoke_model, monkeypatch, impl,
+                                            cell):
+    """Waves of the paged ``decode_step`` over pools pre-filled with a
+    sentinel: slots start at different waves (ragged), slot 1 never starts
+    (an all ``-1`` row), slot 0 crosses a page boundary.  Only the live
+    slots' (layer, page, offset) rows and layer 0's dump page may change, and
+    every layer's written K/V and every live logit equal the contiguous
+    cache's ``decode_step`` on the same tokens."""
+    cfg, params = smoke_model
+    if cell == "two_blocks":
+        cfg = _two_block_variant(cfg)
+        params = T.init_params(jax.random.PRNGKey(1), cfg)
+    ps, n_pages, m, sentinel = 4, 11, 3, 3.0
+    start = [0, None, 2, 5]                  # wave at which each slot starts
+    n_waves = 7                              # slot 0 reaches position 6
+    pages = {0: [1, 2], 2: [4, 5], 3: [7]}   # page 3, 6, 8.. never written
+    bt = np.full((len(start), m), -1, np.int32)
+    for s_, pg in pages.items():
+        bt[s_, :len(pg)] = pg
+    rng = np.random.default_rng(0)
+    toks = rng.integers(0, cfg.vocab_size, (len(start), n_waves))
+
+    spec = PagedKVSpec(page_size=ps, n_pages=n_pages, max_pages_per_slot=m)
+    pools = jax.tree.map(lambda a: jnp.full_like(a, sentinel),
+                         kv_cache.init_pools(cfg, spec, jnp.float32))
+    caches = T.init_cache(cfg, len(start), ps * m, jnp.float32)
+    contiguous = jax.jit(functools.partial(T.decode_step, cfg=cfg))
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", impl)
+    paged = jax.jit(functools.partial(T.decode_step, cfg=cfg))
+
+    for t in range(n_waves):
+        live = [st is not None and t >= st for st in start]
+        pos = np.asarray([t - st if lv else 0
+                          for st, lv in zip(start, live)], np.int32)
+        tok = np.asarray([toks[i, p] for i, p in enumerate(pos)], np.int32)
+        bt_t = np.where(np.asarray(live)[:, None], bt, -1)
+        want, caches = contiguous(params, token=jnp.asarray(tok),
+                                  position=jnp.asarray(pos), caches=caches)
+        got, pools = paged(params, token=jnp.asarray(tok),
+                           position=(jnp.asarray(pos), jnp.asarray(bt_t)),
+                           caches=pools)
+        np.testing.assert_allclose(np.asarray(got)[live],
+                                   np.asarray(want)[live], atol=1e-4)
+
+    lengths = {s_: n_waves - st for s_, st in enumerate(start)
+               if st is not None}
+    for blk, pool in pools["s0"].items():
+        for name, leaf in pool.items():
+            leaf = np.asarray(leaf)          # (R, P, Hkv, hd, ps)
+            ref_kv = np.asarray(caches["s0"][blk][name[0]])  # (R, B, L, ...)
+            changed = (leaf != sentinel).any(axis=(2, 3))    # (R, P, ps)
+            allowed = np.zeros_like(changed)
+            # inactive slots write page 0 of the flattened pool: layer 0's
+            # dump page, from every layer
+            allowed[0, 0] = True
+            for s_, n in lengths.items():
+                for p in range(n):
+                    pg, off = bt[s_, p // ps], p % ps
+                    allowed[:, pg, off] = True
+                    np.testing.assert_allclose(leaf[:, pg, :, :, off],
+                                               ref_kv[:, s_, p], atol=1e-5)
+            assert not (changed & ~allowed).any(), (blk, name)
+
+
+def test_wave_carries_pools_and_aliases_them(smoke_model):
+    """The wave's layer scan has no pool-shaped array among its scanned
+    inputs or outputs (the pools ride its carry), and the compiled wave
+    still aliases the donated pools to its outputs."""
+    cfg, params = smoke_model
+    assert cfg.stages[0].repeat >= 2
+    spec = PagedKVSpec(page_size=4, n_pages=9, max_pages_per_slot=3)
+    engine = ServeEngine(cfg, params, kv_spec=spec, n_slots=2)
+    args = (params, jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+            jnp.full((2, 3), -1, jnp.int32), engine.pools,
+            jax.random.PRNGKey(0))
+    page = jax.tree.leaves(engine.pools)[0].shape[2:]    # (Hkv, hd, ps)
+
+    def pool_shaped(v):
+        shape = getattr(v.aval, "shape", ())
+        return tuple(shape[-3:]) == page and \
+            int(np.prod(shape[:-3])) >= spec.n_pages
+
+    def scans(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scans(sub)
+
+    carried = 0
+    for eqn in scans(jax.make_jaxpr(engine._step_impl)(*args).jaxpr):
+        n_in = eqn.params["num_consts"] + eqn.params["num_carry"]
+        xs, ys = eqn.invars[n_in:], eqn.outvars[eqn.params["num_carry"]:]
+        assert not any(pool_shaped(v) for v in (*xs, *ys))
+        carried += sum(pool_shaped(v)
+                       for v in eqn.invars[eqn.params["num_consts"]:n_in])
+    assert carried == 2                      # the K and V pools, one scan
+
+    hlo = engine._step.lower(*args).compile().as_text()
+    header = hlo.splitlines()[0]
+    aliased = {int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    first_pool = len(jax.tree.leaves(params)) + 3       # tok, positions, bt
+    assert aliased == set(range(
+        first_pool, first_pool + len(jax.tree.leaves(engine.pools))))
 
 
 # ---------------------------------------------------------------------------

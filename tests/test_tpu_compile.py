@@ -80,11 +80,12 @@ def test_flash_attention_compiles(chip, mode, seq):
 
 def test_paged_decode_compiles(chip):
     """One decode step at the serving shape of ``launch/serve.py``: 4 slots,
-    16-token pages, 160-token context, the pool sized as serve.py sizes it."""
-    slots, ps, m = 4, 16, 10
+    128-token pages, 160-token context, the pool sized as serve.py sizes
+    it."""
+    slots, ps, m = 4, 128, 2
     args = (_sds(chip, (slots, H, HD)),
-            _sds(chip, (2 * slots * m + 1, ps, HKV, HD)),
-            _sds(chip, (2 * slots * m + 1, ps, HKV, HD)),
+            _sds(chip, (2 * slots * m + 1, HKV, HD, ps)),
+            _sds(chip, (2 * slots * m + 1, HKV, HD, ps)),
             _sds(chip, (slots, m), jnp.int32), _sds(chip, (slots,), jnp.int32))
     compiled = _compile(
         lambda *a: ops.paged_decode_attention(*a, impl="pallas"), *args)
